@@ -362,7 +362,6 @@ def sharded_best_two(coords, ca, slots, slot_cache, H, h_repo, mesh,
     (NETDUEL's promotion re-arm, the scanned LOCALSWAP) runs when a
     ``DeviceInstance`` carries mesh axes.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.knn.ops import _pad_axis, mesh_axes_size
@@ -380,11 +379,11 @@ def sharded_best_two(coords, ca, slots, slot_cache, H, h_repo, mesh,
         return _best_two_rows(rows_s, keys_s, slots_s, slot_cache_s, H_s,
                               h_repo_s, metric, gamma, has_ca)
 
-    best1, arg1, best2 = shard_map(
+    best1, arg1, best2 = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(tuple(axes), None), P(), P(), P(), P(), P()),
         out_specs=(P(None, tuple(axes)),) * 3,
-        check_rep=False)(rows, keys, slots, slot_cache, H, h_repo)
+        check_vma=False)(rows, keys, slots, slot_cache, H, h_repo)
     return best1[:, :n_obj], arg1[:, :n_obj], best2[:, :n_obj]
 
 
@@ -422,7 +421,6 @@ def sharded_best_two_tables(coords, ca, slots, slot_cache, H, mesh,
     """Mesh-sharded pre-fold tables (b1, a1, b2, a2): the request axis is
     shard_mapped over ``axes`` exactly like :func:`sharded_best_two`, so
     per-row results are bit-identical at any shard count."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.knn.ops import _pad_axis, mesh_axes_size
@@ -440,11 +438,11 @@ def sharded_best_two_tables(coords, ca, slots, slot_cache, H, mesh,
         return _best_two_rows_pre(rows_s, keys_s, slots_s, slot_cache_s,
                                   H_s, metric, gamma, has_ca)
 
-    b1, a1, b2, a2 = shard_map(
+    b1, a1, b2, a2 = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(tuple(axes), None), P(), P(), P(), P()),
         out_specs=(P(None, tuple(axes)),) * 4,
-        check_rep=False)(rows, keys, slots, slot_cache, H)
+        check_vma=False)(rows, keys, slots, slot_cache, H)
     return b1[:, :n_obj], a1[:, :n_obj], b2[:, :n_obj], a2[:, :n_obj]
 
 
@@ -735,9 +733,40 @@ class DeviceInstance:
 
     def total_cost(self, slots) -> float:
         """C(A) evaluated on device (f32) — the only total-cost path that
-        exists for catalogs past CA_MATERIALIZE_MAX."""
-        best1, _, _ = self.best_two(jnp.asarray(slots))
-        return float(jnp.sum(self.lam * best1))
+        exists for catalogs past CA_MATERIALIZE_MAX.
+
+        Only requested objects are priced: a zero-rate row adds nothing
+        to the sum, and dropping it bounds the (rows × slots) cost table
+        by the demand's support instead of the catalog (10⁶ objects ×
+        86,016 slots would be a 344 GB table). The support is padded to
+        a power of two with zero-rate copies of its first row, so
+        successive observed windows reuse a few compiled shapes."""
+        lam = np.asarray(self.lam)
+        supp = np.nonzero(lam.sum(axis=0) > 0)[0]
+        if supp.size == 0:
+            return 0.0
+        n = 1 << int(supp.size - 1).bit_length()
+        rows = np.concatenate([supp, np.full(n - supp.size, supp[0])])
+        lam_s = np.zeros((lam.shape[0], n), np.float32)
+        lam_s[:, :supp.size] = lam[:, supp]
+        ca = self.ca if self.ca is not None else jnp.zeros((0, 0), jnp.float32)
+        return float(_support_cost_device(
+            self.coords, ca, jnp.asarray(rows, jnp.int32),
+            jnp.asarray(lam_s), jnp.asarray(slots), self.slot_cache,
+            self.H, self.h_repo, self.metric, self.gamma,
+            self.ca is not None))
+
+
+@functools.partial(jax.jit, static_argnames=("metric", "gamma", "has_ca"))
+def _support_cost_device(coords, ca, rows, lam_s, slots, slot_cache, H,
+                         h_repo, metric: str, gamma: float, has_ca: bool):
+    """Σ λ·best1 over the request rows ``rows`` (the demand's support)."""
+    r = ca[rows] if has_ca else coords[rows]
+    keys = jnp.zeros((0, 0), jnp.float32) if has_ca \
+        else coords[jnp.maximum(slots, 0)]
+    best1, _, _ = _best_two_rows(r, keys, slots, slot_cache, H, h_repo,
+                                 metric, gamma, has_ca)
+    return jnp.sum(lam_s * best1)
 
 
 def random_slots(inst: Instance, rng: np.random.Generator) -> np.ndarray:

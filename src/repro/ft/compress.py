@@ -15,7 +15,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 # The int8 quantizer lives in kernels/quant.py now — one implementation
@@ -25,21 +24,7 @@ from jax.sharding import PartitionSpec as P
 # gradient-exchange callers and tests keep their import site.
 from repro.kernels.quant import dequantize_int8, quantize_int8
 
-__all__ = ["axis_size", "quantize_int8", "dequantize_int8",
-           "compressed_crosspod_mean"]
-
-
-def axis_size(axis_name: str) -> jax.Array | int:
-    """Size of a named mesh axis, from inside shard_map/vmap/pmap.
-
-    ``jax.lax.axis_size`` was removed from the installed JAX; a psum of
-    ones over the axis is the portable spelling (constant-folded at
-    trace time).
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+__all__ = ["quantize_int8", "dequantize_int8", "compressed_crosspod_mean"]
 
 
 def _crosspod_leaf(g: jax.Array, pod_axis: str) -> jax.Array:
@@ -67,8 +52,8 @@ def compressed_crosspod_mean(grads: Any, mesh, pod_axis: str = "pod",
     spec = P()        # gradients replicated within the mapped axes
 
     def apply(leaf):
-        fn = shard_map(per_shard, mesh=mesh,
+        fn = jax.shard_map(per_shard, mesh=mesh,
                        in_specs=spec, out_specs=spec,
-                       check_rep=False)
+                       check_vma=False)
         return fn(leaf)
     return jax.tree.map(apply, grads)

@@ -11,9 +11,8 @@ device count and validating that every parameter still shards.
 """
 from __future__ import annotations
 
-import jax
-
 from repro.configs.base import ArchConfig
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import MeshShardPolicy
 from repro.models import schema as schema_api
 
@@ -23,8 +22,8 @@ def plan_mesh(n_devices: int, model_parallelism: int = 16):
     the device count doesn't support it."""
     while n_devices % model_parallelism and model_parallelism > 1:
         model_parallelism //= 2
-    return jax.make_mesh((n_devices // model_parallelism,
-                          model_parallelism), ("data", "model"))
+    return make_mesh((n_devices // model_parallelism, model_parallelism),
+                     ("data", "model"))
 
 
 def reshard_plan(cfg: ArchConfig, mesh, mode: str = "train"):

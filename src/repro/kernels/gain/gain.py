@@ -34,7 +34,8 @@ H_SENTINEL = 1.0e30      # "off-path" finite stand-in for +inf
 
 
 def _gain_kernel(x_ref, y_ref, lam_ref, cur_ref, h_ref, out_ref, *,
-                 metric: str, gamma: float, n_caches: int):
+                 metric: str, gamma: float, n_caches: int,
+                 n_feat: int | None):
     rt = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)          # (BR, D)
     y = y_ref[...].astype(jnp.float32)          # (BO, D)
@@ -42,7 +43,7 @@ def _gain_kernel(x_ref, y_ref, lam_ref, cur_ref, h_ref, out_ref, *,
     cur = cur_ref[...].astype(jnp.float32)      # (BR, 1)
     h = h_ref[...].astype(jnp.float32)          # (BR, J)
 
-    ca = _distance_block(x, y, metric)          # (BR, BO)
+    ca = _distance_block(x, y, metric, n_feat)  # (BR, BO)
     if gamma != 1.0:
         ca = jnp.power(jnp.maximum(ca, 0.0), gamma)
     slack = cur - ca                            # (BR, BO)
@@ -57,19 +58,21 @@ def _gain_kernel(x_ref, y_ref, lam_ref, cur_ref, h_ref, out_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "metric", "gamma", "br", "bo", "interpret"))
+    "metric", "gamma", "br", "bo", "interpret", "n_feat"))
 def gain_pallas(x: jax.Array, y: jax.Array, lam: jax.Array, cur: jax.Array,
                 hreq: jax.Array, metric: str = "l2", gamma: float = 1.0,
                 br: int = DEFAULT_BR, bo: int = DEFAULT_BO,
-                interpret: bool = True) -> jax.Array:
-    """Pre-padded inputs: R % br == 0, O % bo == 0. Returns (J, O) f32."""
+                interpret: bool = True, n_feat: int | None = None
+                ) -> jax.Array:
+    """Pre-padded inputs: R % br == 0, O % bo == 0. Returns (J, O) f32.
+    ``n_feat`` is the feature count before lane padding (None: all D)."""
     R, D = x.shape
     O, _ = y.shape
     J = hreq.shape[1]
     assert R % br == 0 and O % bo == 0, (R, O, br, bo)
     grid = (O // bo, R // br)
     kernel = functools.partial(_gain_kernel, metric=metric, gamma=gamma,
-                               n_caches=J)
+                               n_caches=J, n_feat=n_feat)
     out = pl.pallas_call(
         kernel,
         grid=grid,

@@ -39,5 +39,5 @@ def greedy_gain(x: jax.Array, y: jax.Array, lam: jax.Array, cur: jax.Array,
     curp = _pad_axis(cur.astype(jnp.float32)[:, None], br, 0, "zero")
     hp = _pad_axis(hreq.astype(jnp.float32), br, 0, "zero")
     out = gain_pallas(xp, yp, lamp, curp, hp, metric=metric, gamma=gamma,
-                      br=br, bo=bo, interpret=interpret)
+                      br=br, bo=bo, interpret=interpret, n_feat=x.shape[1])
     return out[:, :n_obj].T

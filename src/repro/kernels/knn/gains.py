@@ -47,7 +47,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import quant
@@ -59,9 +58,10 @@ DEFAULT_BO = 256
 H_SENTINEL = 1.0e30      # finite stand-in for +inf (off-path) retrieval cost
 
 
-def _ca_block(x, y, metric: str, gamma: float):
+def _ca_block(x, y, metric: str, gamma: float, n_feat: int | None = None):
     """(BR, BO) approximation-cost tile C_a = d(x, y)^γ (f32)."""
-    ca = _distance_block(x.astype(jnp.float32), y.astype(jnp.float32), metric)
+    ca = _distance_block(x.astype(jnp.float32), y.astype(jnp.float32), metric,
+                         n_feat)
     if gamma != 1.0:
         ca = jnp.power(jnp.maximum(ca, 0.0), gamma)
     return ca
@@ -88,7 +88,8 @@ def duel_virtual_costs(coords, ca, obj, virt_safe, h_slots,
 
 
 def _gains_kernel(x_ref, y_ref, lam_ref, cur_ref, h_ref, out_ref, *,
-                  metric: str, gamma: float, n_ingress: int, n_caches: int):
+                  metric: str, gamma: float, n_ingress: int, n_caches: int,
+                  n_feat: int | None):
     rt = pl.program_id(1)
     x = x_ref[...]                              # (BR, D) request coords
     y = y_ref[...]                              # (BO, D) candidate coords
@@ -96,7 +97,7 @@ def _gains_kernel(x_ref, y_ref, lam_ref, cur_ref, h_ref, out_ref, *,
     cur = cur_ref[...].astype(jnp.float32)      # (I, BR)
     h = h_ref[...].astype(jnp.float32)          # (I, J)
 
-    ca = _ca_block(x, y, metric, gamma)         # (BR, BO) — computed once
+    ca = _ca_block(x, y, metric, gamma, n_feat)  # (BR, BO) — computed once
 
     @pl.when(rt == 0)
     def _init():
@@ -111,10 +112,12 @@ def _gains_kernel(x_ref, y_ref, lam_ref, cur_ref, h_ref, out_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "metric", "gamma", "br", "bo", "interpret"))
+    "metric", "gamma", "br", "bo", "interpret", "n_feat"))
 def _gains_pallas(x, y, lam, cur, hreq, metric: str, gamma: float,
-                  br: int, bo: int, interpret: bool) -> jax.Array:
-    """Pre-padded inputs: R % br == 0, O % bo == 0. Returns (J, O) f32."""
+                  br: int, bo: int, interpret: bool,
+                  n_feat: int | None = None) -> jax.Array:
+    """Pre-padded inputs: R % br == 0, O % bo == 0. Returns (J, O) f32.
+    ``n_feat`` is the feature count before lane padding (None: all D)."""
     R, D = x.shape
     O, _ = y.shape
     I, J = hreq.shape
@@ -122,7 +125,7 @@ def _gains_pallas(x, y, lam, cur, hreq, metric: str, gamma: float,
     assert lam.shape == cur.shape == (I, R), (lam.shape, cur.shape)
     grid = (O // bo, R // br)
     kernel = functools.partial(_gains_kernel, metric=metric, gamma=gamma,
-                               n_ingress=I, n_caches=J)
+                               n_ingress=I, n_caches=J, n_feat=n_feat)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -240,7 +243,7 @@ def placement_gains(x: jax.Array, y: jax.Array, lam: jax.Array,
     lamp = _pad_axis(lam, br, 1, "zero")
     curp = _pad_axis(cur, br, 1, "zero")
     out = _gains_pallas(xp, yp, lamp, curp, hreq, metric=metric, gamma=gamma,
-                        br=br, bo=bo, interpret=interpret)
+                        br=br, bo=bo, interpret=interpret, n_feat=x.shape[1])
     return out[:, :n_obj].T
 
 
@@ -311,9 +314,9 @@ def sharded_placement_gains(x: jax.Array, y: jax.Array, lam: jax.Array,
                                use_pallas=use_pallas, interpret=interpret,
                                quantize=quantize)
 
-    out = shard_map(
+    out = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), spec, P(), P(), P()),
         out_specs=P(tuple(axes), None),
-        check_rep=False)(x.astype(jnp.float32), yp, lam, cur, hreq)
+        check_vma=False)(x.astype(jnp.float32), yp, lam, cur, hreq)
     return out[:n_obj]

@@ -12,8 +12,8 @@ Layout / tiling:
     the (BQ, BK) distance block with one MXU matmul via the
     |q|² + |k|² − 2·q·kᵀ identity (f32 accumulation).
   * the L1 path (the paper's norm-1 experiments) has no matmul form; it
-    accumulates |q−k| over D in chunks of ``DC`` to bound the
-    (BQ, BK, DC) broadcast temporary — VPU work, still VMEM-resident.
+    accumulates |q−k| one feature at a time over lane-dense (BQ, BK)
+    tiles — VPU work, still VMEM-resident.
   * D is zero-padded to a lane multiple and K is padded by *repeating
     key 0* — ties break to the lower index, so padded duplicates can
     never win over the genuine entry (see ops.py).
@@ -31,36 +31,43 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BQ = 256
 DEFAULT_BK = 256
-L1_CHUNK = 8
 _INF = 3.0e38  # python float: jnp scalars would be captured as consts
 
 
-def _distance_block(q, k, metric: str):
-    """(BQ, BK) distances between f32 tiles q (BQ, D), k (BK, D)."""
+def _distance_block(q, k, metric: str, n_feat: int | None = None):
+    """(BQ, BK) distances between f32 tiles q (BQ, D), k (BK, D).
+
+    ``n_feat`` is the feature count before lane padding: the l1 loop
+    stops there, since a zero-padded feature adds |0 − 0| = 0.
+
+    The l2 dot runs at HIGHEST precision: the default f32 matmul on the
+    TPU rounds its inputs to bf16, which moves costs by far more than
+    the f32 ulp the exact-lookup contracts are stated in."""
     if metric in ("l2", "l2sq"):
         d2 = (jnp.sum(q * q, axis=-1)[:, None]
               + jnp.sum(k * k, axis=-1)[None, :]
-              - 2.0 * jnp.dot(q, k.T, preferred_element_type=jnp.float32))
+              - 2.0 * jnp.dot(q, k.T, preferred_element_type=jnp.float32,
+                              precision=jax.lax.Precision.HIGHEST))
         d2 = jnp.maximum(d2, 0.0)
         return d2 if metric == "l2sq" else jnp.sqrt(d2)
     if metric == "l1":
-        bq, d = q.shape
-        bk = k.shape[0]
-        acc = jnp.zeros((bq, bk), dtype=jnp.float32)
-        for c in range(0, d, L1_CHUNK):
-            qc = q[:, c:c + L1_CHUNK][:, None, :]      # (BQ, 1, DC)
-            kc = k[:, c:c + L1_CHUNK][None, :, :]      # (1, BK, DC)
-            acc = acc + jnp.sum(jnp.abs(qc - kc), axis=-1)
+        # one feature per step over lane-dense (BQ, BK) tiles: a query
+        # column against a row of the transposed keys, accumulated in
+        # feature order
+        kt = k.T                                       # (D, BK)
+        acc = jnp.zeros((q.shape[0], k.shape[0]), dtype=jnp.float32)
+        for c in range(n_feat or q.shape[1]):
+            acc = acc + jnp.abs(q[:, c:c + 1] - kt[c:c + 1, :])
         return acc
     raise ValueError(metric)
 
 
 def _knn_kernel(q_ref, k_ref, mind_ref, argm_ref, *, bk: int, metric: str,
-                gamma: float):
+                gamma: float, n_feat: int | None):
     kt = pl.program_id(1)
     q = q_ref[...].astype(jnp.float32)
     k = k_ref[...].astype(jnp.float32)
-    cost = _distance_block(q, k, metric)
+    cost = _distance_block(q, k, metric, n_feat)
     if gamma != 1.0:
         cost = jnp.power(jnp.maximum(cost, 0.0), gamma)
     local_min = jnp.min(cost, axis=1, keepdims=True)               # (BQ, 1)
@@ -88,7 +95,7 @@ def _select_at(idx_col, block, fill):
 def _fused_kernel(q_ref, k_ref, hk_ref, meta_ref,
                   cost_ref, ca_ref, lvl_ref, slot_ref, pay_ref,
                   *, nk: int, metric: str, gamma: float, h_repo: float,
-                  repo_level: int, fold_repo: bool):
+                  repo_level: int, fold_repo: bool, n_feat: int | None):
     """Segmented 1-NN over the concatenation of all cache levels.
 
     Per key tile we get, besides the (BK, D) key block, a (1, BK) f32 row
@@ -112,7 +119,7 @@ def _fused_kernel(q_ref, k_ref, hk_ref, meta_ref,
     kt = pl.program_id(1)
     q = q_ref[...].astype(jnp.float32)
     k = k_ref[...].astype(jnp.float32)
-    ca = _distance_block(q, k, metric)
+    ca = _distance_block(q, k, metric, n_feat)
     if gamma != 1.0:
         ca = jnp.power(jnp.maximum(ca, 0.0), gamma)
     meta = meta_ref[...]                               # (4, BK) int32
@@ -155,14 +162,15 @@ def _fused_kernel(q_ref, k_ref, hk_ref, meta_ref,
 
 @functools.partial(jax.jit, static_argnames=(
     "metric", "gamma", "h_repo", "repo_level", "bq", "bk", "interpret",
-    "fold_repo"))
+    "fold_repo", "n_feat"))
 def fused_lookup_pallas(queries: jax.Array, keys: jax.Array,
                         h_key: jax.Array, meta: jax.Array,
                         metric: str = "l2", gamma: float = 1.0,
                         h_repo: float = 0.0, repo_level: int = -1,
                         bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                        interpret: bool = True,
-                        fold_repo: bool = True) -> tuple[jax.Array, ...]:
+                        interpret: bool = True, fold_repo: bool = True,
+                        n_feat: int | None = None
+                        ) -> tuple[jax.Array, ...]:
     """Fused multi-level 1-NN: one pallas_call over ΣK_j concatenated
     keys, minimizing C_a(q, k)^γ + h(level(k)) with the repository folded
     in as a virtual key. Inputs must be pre-padded (Q % bq == 0,
@@ -172,6 +180,7 @@ def fused_lookup_pallas(queries: jax.Array, keys: jax.Array,
     (level, slot, payload, valid). Returns per query (cost, approx_cost,
     level, slot, payload). ``fold_repo=False`` is the shard-local entry:
     segment minima only, no repository fold (see _fused_kernel).
+    ``n_feat`` is the feature count before lane padding (None: all D).
     """
     Q, D = queries.shape
     K, _ = keys.shape
@@ -181,7 +190,8 @@ def fused_lookup_pallas(queries: jax.Array, keys: jax.Array,
     grid = (Q // bq, K // bk)
     kernel = functools.partial(
         _fused_kernel, nk=K // bk, metric=metric, gamma=gamma,
-        h_repo=h_repo, repo_level=repo_level, fold_repo=fold_repo)
+        h_repo=h_repo, repo_level=repo_level, fold_repo=fold_repo,
+        n_feat=n_feat)
     out_block = pl.BlockSpec((bq, 1), lambda qt, kt: (qt, 0))
     cost, ca, lvl, slot, pay = pl.pallas_call(
         kernel,
@@ -206,17 +216,19 @@ def fused_lookup_pallas(queries: jax.Array, keys: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "metric", "gamma", "bq", "bk", "interpret"))
+    "metric", "gamma", "bq", "bk", "interpret", "n_feat"))
 def knn_pallas(queries: jax.Array, keys: jax.Array, metric: str = "l2",
                gamma: float = 1.0, bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-               interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+               interpret: bool = True, n_feat: int | None = None
+               ) -> tuple[jax.Array, jax.Array]:
     """Blocked 1-NN. Inputs must be pre-padded: Q % bq == 0, K % bk == 0,
     with key padding = repeats of keys[0] (see ops.pad_for_knn)."""
     Q, D = queries.shape
     K, _ = keys.shape
     assert Q % bq == 0 and K % bk == 0, (Q, K, bq, bk)
     grid = (Q // bq, K // bk)
-    kernel = functools.partial(_knn_kernel, bk=bk, metric=metric, gamma=gamma)
+    kernel = functools.partial(_knn_kernel, bk=bk, metric=metric, gamma=gamma,
+                               n_feat=n_feat)
     mind, argm = pl.pallas_call(
         kernel,
         grid=grid,

@@ -22,7 +22,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import tracecount
@@ -89,7 +88,7 @@ def nearest_approximizer(queries: jax.Array, keys: jax.Array,
     qp, kp = pad_for_knn(queries.astype(jnp.float32),
                          keys.astype(jnp.float32), bq, bk)
     mind, argm = knn_pallas(qp, kp, metric=metric, gamma=gamma, bq=bq, bk=bk,
-                            interpret=interpret)
+                            interpret=interpret, n_feat=queries.shape[1])
     return mind[:nq], argm[:nq]
 
 
@@ -146,7 +145,7 @@ def fused_lookup(queries: jax.Array, keys: jax.Array, h_key: jax.Array,
     cost, ca, lvl, slot, pay = fused_lookup_pallas(
         qp, kp, hp, mp, metric=metric, gamma=gamma, h_repo=h_repo,
         repo_level=repo_level, bq=bq, bk=bk, interpret=interpret,
-        fold_repo=fold_repo)
+        fold_repo=fold_repo, n_feat=queries.shape[1])
     return cost[:nq], ca[:nq], lvl[:nq], slot[:nq], pay[:nq]
 
 
@@ -201,11 +200,11 @@ def sharded_fused_lookup(queries: jax.Array, keys: jax.Array,
             interpret=interpret, fold_repo=False)
         return (cost[None], ca[None], lvl[None], slot[None], pay[None])
 
-    parts = shard_map(
+    parts = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), spec, spec, P(None, tuple(axes))),
         out_specs=(spec,) * 5,
-        check_rep=False)(queries, keys, h_key, meta)
+        check_vma=False)(queries, keys, h_key, meta)
     return reduce_shard_minima(*parts, h_repo=h_repo,
                                repo_level=repo_level)
 
@@ -375,12 +374,12 @@ def sharded_quantized_fused_lookup(queries: jax.Array, keys: jax.Array,
         return (cost[None], ca[None], lvl[None], slot[None], pay[None],
                 bound[None])
 
-    parts = shard_map(
+    parts = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), spec, spec, P(None, tuple(axes)),
                   spec, spec, spec, spec),
         out_specs=(spec,) * 6,
-        check_rep=False)(queries, keys, h_key, meta,
+        check_vma=False)(queries, keys, h_key, meta,
                          kq.q, kq.scale, kq.radius, kq.sq_norm)
     *minima, bounds = parts
     red = reduce_shard_minima(*minima, h_repo=h_repo,
@@ -505,12 +504,12 @@ def sharded_pruned_fused_lookup(queries: jax.Array, keys: jax.Array,
         return (cost[None], ca[None], lvl[None], slot[None], pay[None],
                 bound[None])
 
-    parts = shard_map(
+    parts = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), spec, spec, P(None, tuple(axes)),
                   P(tuple(axes)), P(tuple(axes))),
         out_specs=(spec,) * 6,
-        check_rep=False)(queries, keys, h_key, meta, proj_s, buckets_s)
+        check_vma=False)(queries, keys, h_key, meta, proj_s, buckets_s)
     *minima, bounds = parts
     red = reduce_shard_minima(*minima, h_repo=h_repo,
                               repo_level=repo_level)

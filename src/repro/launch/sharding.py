@@ -313,8 +313,10 @@ class LookupShardPolicy:
             present = tuple(mesh.axis_names)
         total = mesh_axes_size(mesh, present)
         spec = _resolve((total,), ("keys",), {"keys": present}, mesh)
+        # PartitionSpec stores a one-axis entry as the bare axis name
         axes = spec[0] if spec[0] is not None else ()
-        return cls(mesh=mesh, axes=tuple(axes), prune=prune,
+        axes = (axes,) if isinstance(axes, str) else tuple(axes)
+        return cls(mesh=mesh, axes=axes, prune=prune,
                    table_seed=table_seed)
 
     @property

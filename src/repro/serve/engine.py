@@ -378,6 +378,13 @@ class SimCacheEngine:
         observable refresh-in-flight flag of the streaming loop)."""
         return self._in_flight
 
+    # -------------------------------------------------------- repository
+    def prefill(self, tokens: jnp.ndarray) -> jax.Array:
+        """Run the repository model on a (B, S) token batch; returns the
+        (B, vocab) logits of the last position."""
+        logits, _ = self._prefill(self.params, {"tokens": tokens})
+        return logits[:, -1, :]
+
     # ------------------------------------------------------- calibration
     def calibrate(self, sample_prompt: jnp.ndarray, n: int = 3) -> float:
         """Measure the repository cost (one prefill batch) in ms and set
@@ -769,8 +776,7 @@ class SimCacheEngine:
             if bucket:
                 sel = _pad_rows(sel, bucket_size(len(miss_idx),
                                                  self.ecfg.min_bucket))
-            logits, _ = self._prefill(self.params, {"tokens": sel})
-            resp = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
+            resp = np.asarray(jnp.argmax(self.prefill(sel), axis=-1))
             self.stats.model_calls += 1
             if self.routing is None and self.simcache is None:
                 # cold engine without a strategy plane: repository cost

@@ -1,15 +1,14 @@
 """Unit tests for ft/compress.py and the shared int8 quantizer it now
 re-exports from kernels/quant.py — round-trip error bounds, the
-explicit all-zero-row guard, metric-space radius bounds, and the
-axis_size compatibility helper (regression for the removed
-``jax.lax.axis_size``; the cross-pod mean itself is exercised on an
-8-device mesh in test_distributed.py)."""
+explicit all-zero-row guard and metric-space radius bounds (the
+cross-pod mean itself is exercised on an 8-device mesh in
+test_distributed.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.ft.compress import axis_size, dequantize_int8, quantize_int8
+from repro.ft.compress import dequantize_int8, quantize_int8
 from repro.kernels import quant
 
 
@@ -78,18 +77,10 @@ def test_quant_row_radius_bounds_roundtrip_distance(metric):
     assert np.all(d <= np.asarray(rows.radius) + 1e-30), metric
 
 
-def test_axis_size_compat_under_named_axis():
-    """axis_size must work inside any named-axis context on current JAX
-    (jax.lax.axis_size was removed; psum(1, axis) is the fallback)."""
-    out = jax.vmap(lambda x: x * axis_size("i"), axis_name="i")(
-        jnp.ones((4,)))
-    np.testing.assert_array_equal(np.asarray(out), 4.0)
-
-
 def test_crosspod_leaf_has_no_removed_api_calls():
     """Regression: _crosspod_leaf called jax.lax.axis_size, removed from
-    the installed JAX — it must go through the compat helper (or not
-    need the size at all, as the gathered leading dim carries it)."""
+    the installed JAX — it needs no axis size, as the gathered leading
+    dim carries it."""
     import inspect
 
     from repro.ft import compress

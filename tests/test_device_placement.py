@@ -223,6 +223,27 @@ def test_device_total_cost_matches_host():
         inst.total_cost(slots), rel=1e-5)
 
 
+@pytest.mark.parametrize("name,make", ALL_INSTANCES)
+@pytest.mark.parametrize("n_req", [1, 5, 37])
+def test_device_total_cost_prices_only_requested_rows(name, make, n_req):
+    """An observed window requests a few objects: the device cost prices
+    only that support (padded to a power of two with zero-rate rows) and
+    still equals the host cost over the whole catalog."""
+    inst = make()
+    rng = np.random.default_rng(n_req)
+    lam = np.zeros_like(inst.dem.lam)
+    req = rng.choice(inst.cat.n, n_req, replace=False)
+    lam[:, req] = rng.random((lam.shape[0], n_req)) + 0.1
+    sparse = Instance(net=inst.net, cat=inst.cat,
+                      dem=demand.Demand(lam=lam / lam.sum()))
+    slots = random_slots(sparse, rng)
+    for materialize in (True, False):
+        dinst = DeviceInstance.from_instance(sparse,
+                                             materialize_ca=materialize)
+        assert dinst.total_cost(slots) == pytest.approx(
+            sparse.total_cost(slots), rel=1e-5), (name, materialize)
+
+
 # ------------------------------------------------------- ties and gain_tol
 def test_gain_tol_near_ties_resolve_by_index():
     """gain_tol regression (host oracle honesty): duplicated catalog

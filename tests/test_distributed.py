@@ -19,6 +19,7 @@ def run_in_subprocess(body: str):
         import jax.numpy as jnp
         import numpy as np
         assert jax.device_count() == 8
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ,
                PYTHONPATH=os.path.join(ROOT, "src"))
@@ -89,7 +90,7 @@ def test_moe_expert_parallel_matches():
 def test_compressed_crosspod_mean():
     run_in_subprocess("""
         from repro.ft.compress import compressed_crosspod_mean
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.standard_normal((16, 64)).astype(np.float32))
         with mesh:
@@ -121,7 +122,7 @@ def test_elastic_remesh_restore():
                  "labels": jnp.asarray(rng.integers(0, cfg.vocab, (8, 16)))}
         losses = []
         for (nd, nm) in ((4, 2), (2, 4), (8, 1)):
-            mesh = jax.make_mesh((nd, nm), ("data", "model"))
+            mesh = make_mesh((nd, nm), ("data", "model"))
             policy = MeshShardPolicy.create(cfg, mesh, "train")
             tree = {"params": policy.param_sharding_tree(
                 schema.param_schema(cfg))}

@@ -39,6 +39,7 @@ from repro.core.simcache import REPO_LEVEL, CacheLevel, SimCacheNetwork
 from repro.kernels.knn import (KMeansPolicy, SimHashPolicy, pad_to_shards,
                                pruned_fused_lookup, pruned_fused_lookup_ref,
                                sharded_pruned_fused_lookup_ref)
+from repro.launch.mesh import make_mesh
 
 EIGHT = jax.device_count() >= 8
 FULL = bool(os.environ.get("CI_FULL"))
@@ -76,7 +77,7 @@ def test_pruned_verify_bit_identical(prune, metric, gamma):
 def test_pruned_verify_bit_identical_sharded(prune):
     """Same contract through the mesh-sharded data plane (per-shard
     tables + fold_repo=False launches + untouched reduction)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     net, rng = make_net(1, [17, 2, 31, 8], [0.0, 0.2, 0.7, 1.3], 3.0)
     snet, _ = make_net(1, [17, 2, 31, 8], [0.0, 0.2, 0.7, 1.3], 3.0,
                        sharded=True, mesh=mesh)
@@ -219,7 +220,7 @@ def test_pruned_tie_break_oracle_eight_shards():
 def test_pruned_tie_break_one_device_mesh():
     net, q = _tie_instance()
     snet, _ = _tie_instance(sharded=True,
-                            mesh=jax.make_mesh((1,), ("data",)))
+                            mesh=make_mesh((1,), ("data",)))
     for verify in (False, True):
         res = snet.lookup(q, prune="lsh", verify=verify)
         assert_results_equal(res, net._lookup_fused(q))
@@ -235,7 +236,7 @@ def test_pruned_tie_break_eight_way_mesh():
     shard's scan, so reduce_shard_minima still breaks the tie to the
     lower shard."""
     snet, q = _tie_instance(sharded=True,
-                            mesh=jax.make_mesh((8,), ("data",)))
+                            mesh=make_mesh((8,), ("data",)))
     net, _ = _tie_instance()
     for prune in ("lsh", "kmeans"):
         for verify in (False, True):
@@ -250,7 +251,7 @@ def test_pruned_tie_break_eight_way_mesh():
                     "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
 @pytest.mark.parametrize("prune", ["lsh", "kmeans"])
 def test_pruned_eight_way_differential(prune):
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     for seed, sizes, hs, h_repo, empty, nq in [
         (0, [5, 9, 3], [0.0, 0.5, 1.0], 2.0, (), 23),
         (3, [4, 1, 4], [0.0, 0.1, 0.4], 2.5, (1,), 11),
@@ -275,7 +276,7 @@ def test_stale_tables_fail_loudly(sharded):
     pruned lookup after mutating ``levels`` without invalidate_layout()
     must raise, not return candidates from the dead layout. After
     invalidation the rebuilt tables agree with the looped path again."""
-    kw = dict(sharded=True, mesh=jax.make_mesh((1,), ("data",))) \
+    kw = dict(sharded=True, mesh=make_mesh((1,), ("data",))) \
         if sharded else {}
     net, rng = make_net(10, [4, 4], [0.0, 0.5], 3.0, "l2", **kw)
     q = jnp.asarray(rng.standard_normal((8, 6)).astype(np.float32))

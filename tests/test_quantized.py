@@ -32,6 +32,7 @@ from repro.kernels import quant
 from repro.kernels.knn import (SimHashPolicy, quantized_fused_lookup,
                                quantized_fused_lookup_ref,
                                sharded_quantized_fused_lookup_ref)
+from repro.launch.mesh import make_mesh
 
 EIGHT = jax.device_count() >= 8
 
@@ -63,7 +64,7 @@ def test_quantized_verify_bit_identical_sharded():
     """Same contract through the mesh-sharded data plane (per-shard
     QuantizedRows + fold_repo=False launches + per-query min of the
     per-shard vT bounds)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     net, rng = make_net(1, [17, 2, 31, 8], [0.0, 0.2, 0.7, 1.3], 3.0)
     snet, _ = make_net(1, [17, 2, 31, 8], [0.0, 0.2, 0.7, 1.3], 3.0,
                        sharded=True, mesh=mesh)
@@ -199,7 +200,7 @@ def test_quant_rows_memo_and_invalidation():
 @pytest.mark.skipif(not EIGHT, reason="needs 8 devices "
                     "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
 def test_quantized_eight_way_differential():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     for seed, sizes, hs, h_repo, nq in CONFIGS:
         net, rng = make_net(seed, sizes, hs, h_repo)
         snet, _ = make_net(seed, sizes, hs, h_repo, sharded=True,
@@ -213,7 +214,7 @@ def test_quantized_eight_way_differential():
 @pytest.mark.skipif(not EIGHT, reason="needs 8 devices "
                     "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
 def test_quantized_plus_lsh_eight_way():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     net, rng = make_net(5, [200, 150, 250], [0.0, 0.4, 0.8], 2.5)
     snet, _ = make_net(5, [200, 150, 250], [0.0, 0.4, 0.8], 2.5,
                        sharded=True, mesh=mesh)

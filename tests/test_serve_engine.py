@@ -9,6 +9,7 @@ import pytest
 from repro.configs.registry import get_smoke_config
 from repro.core import catalog as catalog_api
 from repro.core import demand as demand_api
+from repro.launch.mesh import make_mesh
 from repro.models import model as model_api
 from repro.serve import EngineConfig, SimCacheEngine
 
@@ -103,8 +104,7 @@ def test_engine_sharded_data_plane_matches_fused():
     mesh-sharded fused path; served stats must match the single-device
     fused engine bit-for-bit on the same trace (here a trivial 1-device
     mesh — the 8-way equivalence is covered by test_sharded_lookup)."""
-    import jax
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     eng_f, cfg, cat = make_engine(algo="greedy")
     eng_s, _, _ = make_engine(algo="greedy", sharded=True, mesh=mesh)
     assert eng_s.lookup_shards is not None
@@ -283,3 +283,39 @@ def test_engine_netduel_online_plane():
         [np.asarray(lv.values)[np.asarray(lv.values) >= 0]
          for lv in eng.simcache.levels]))
     assert stored.size == eng.duel.slots_np.size
+
+
+def test_build_engine_serves_and_rejects_encdec():
+    """The launcher's shared construction: weights from the seed, the
+    engine over the catalog; an encoder-decoder arch is refused."""
+    from repro.launch.serve import build_engine
+    cfg = dataclasses.replace(get_smoke_config("granite-3-2b"),
+                              n_layers=2, d_model=64, n_heads=4,
+                              n_kv_heads=2, head_dim=16, d_ff=128, vocab=256)
+    cat = catalog_api.embedding_catalog(n=200, dim=8, seed=2)
+    ecfg = EngineConfig(k_device=4, k_pod=4, k_global=4)
+    eng = build_engine(cfg, ecfg, cat, seed=3)
+    out, stats = eng.serve(np.arange(4), jnp.zeros((4, 8), jnp.int32))
+    assert stats.model_calls == 1 and all(o is not None for o in out)
+    assert eng.prefill(jnp.zeros((2, 8), jnp.int32)).shape == (2, cfg.vocab)
+    with pytest.raises(ValueError):
+        build_engine(get_smoke_config("whisper-small"), ecfg, cat)
+
+
+def test_compile_cache_honours_env_then_fixed_path(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it
+    the cache lives at the fixed <checkout>/.jax_cache."""
+    import jax
+
+    from repro.launch.serve import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    assert enable_compile_cache(tmp_path) == str(tmp_path / "env")
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        fixed = str(tmp_path / ".jax_cache")
+        assert enable_compile_cache(tmp_path) == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -40,6 +40,7 @@ from conftest import assert_results_equal, make_net
 
 from repro.core.simcache import REPO_LEVEL, CacheLevel, SimCacheNetwork
 from repro.kernels.knn import sharded_fused_lookup_ref
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 EIGHT = jax.device_count() >= 8
@@ -97,7 +98,7 @@ def test_sharded_oracle_empty_levels_and_repo(n_shards):
 def test_sharded_one_device_mesh_bit_identical():
     """The real shard_map path on a trivial 1-device mesh: sharded ==
     fused == looped, bitwise (γ = 1)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     net, rng = make_net(1, [17, 2, 31, 8], [0.0, 0.2, 0.7, 1.3], 3.0)
     snet, _ = make_net(1, [17, 2, 31, 8], [0.0, 0.2, 0.7, 1.3], 3.0,
                        sharded=True, mesh=mesh)
@@ -107,7 +108,7 @@ def test_sharded_one_device_mesh_bit_identical():
 
 
 def test_sharded_no_levels_serves_repo():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     net = SimCacheNetwork(levels=[], h_repo=4.5, metric="l2",
                           sharded=True, mesh=mesh)
     q = jnp.asarray(np.random.default_rng(0)
@@ -125,7 +126,7 @@ def test_stale_layout_then_invalidate(sharded):
     invalidate_layout() keeps serving the *stale* concatenation (old
     results, verbatim); invalidate_layout() restores agreement with the
     looped path — for both the fused and the sharded data plane."""
-    kw = dict(sharded=True, mesh=jax.make_mesh((1,), ("data",))) \
+    kw = dict(sharded=True, mesh=make_mesh((1,), ("data",))) \
         if sharded else {}
     net, rng = make_net(10, [4, 4], [0.0, 0.5], 3.0, "l2", **kw)
     q = jnp.asarray(rng.standard_normal((8, 6)).astype(np.float32))
@@ -145,7 +146,7 @@ def test_stale_layout_then_invalidate(sharded):
 
 
 def test_invalidate_layout_clears_sharded_memo():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     net, rng = make_net(11, [6, 3], [0.0, 0.4], 2.0, "l2",
                         sharded=True, mesh=mesh)
     q = jnp.asarray(rng.standard_normal((4, 6)).astype(np.float32))
@@ -162,14 +163,14 @@ def test_lookup_shard_policy_contract():
     meshes); n_shards is the product of the chosen axis sizes."""
     from repro.launch.sharding import LookupShardPolicy
 
-    pol = LookupShardPolicy.create(jax.make_mesh((1,), ("data",)))
+    pol = LookupShardPolicy.create(make_mesh((1,), ("data",)))
     assert pol.axes == ("data",) and pol.n_shards == 1
 
-    pol2 = LookupShardPolicy.create(jax.make_mesh((1, 1),
+    pol2 = LookupShardPolicy.create(make_mesh((1, 1),
                                                   ("data", "model")))
     assert pol2.axes == ("model", "data")        # model preferred first
     # unrecognised axis names: shard over whatever the mesh has
-    pol3 = LookupShardPolicy.create(jax.make_mesh((1,), ("lookup",)))
+    pol3 = LookupShardPolicy.create(make_mesh((1,), ("lookup",)))
     assert pol3.axes == ("lookup",)
 
     # shard-count arithmetic at a multi-device count (mesh shape is the
@@ -178,6 +179,18 @@ def test_lookup_shard_policy_contract():
         shape = {"model": 4, "data": 2}
     pol4 = LookupShardPolicy(mesh=_Mesh(), axes=("model", "data"))
     assert pol4.n_shards == 8
+
+
+def test_make_mesh_builds_auto_axes():
+    """Every repository mesh has Auto axes: the cross-shard reduction's
+    take_along_axis and the model's gathers are written for shardings
+    the compiler propagates (jax.make_mesh alone builds Explicit axes)."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_debug_mesh, make_lookup_mesh
+    for mesh in (make_mesh((1, 1), ("data", "model")), make_lookup_mesh(),
+                 make_debug_mesh(1, 1)):
+        assert all(t == AxisType.Auto for t in mesh.axis_types), mesh
 
 
 # ------------------------------------------------------- dtype contract
@@ -204,7 +217,7 @@ def test_from_placement_sentinel_values_dtype():
 @pytest.mark.parametrize("metric,gamma", [("l2", 1.0), ("l1", 1.0),
                                           ("l2sq", 2.0)])
 def test_sharded_eight_way_differential(metric, gamma):
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     for seed, sizes, hs, h_repo, nq in [
         (0, [5, 9, 3], [0.0, 0.5, 1.0], 2.0, 23),      # K=17: pad to 24
         (1, [17, 2, 31, 8], [0.0, 0.2, 0.7, 1.3], 3.0, 1),   # B=1
@@ -224,7 +237,7 @@ def test_sharded_eight_way_differential(metric, gamma):
 @pytest.mark.skipif(not EIGHT, reason="needs 8 devices "
                     "(XLA_FLAGS=--xla_force_host_platform_device_count=8)")
 def test_sharded_eight_way_tie_break():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     net, snet, q = _tie_instance(mesh)
     rf, rs = net._lookup_fused(q), snet.lookup(q)
     assert_results_equal(rs, rf)
@@ -255,7 +268,7 @@ def _tie_instance(mesh):
 
 def test_sharded_tie_break_oracle_any_devices():
     """Same tie instance, via the chunked oracle (no mesh needed)."""
-    net, _, q = _tie_instance(jax.make_mesh((1,), ("data",)))
+    net, _, q = _tie_instance(make_mesh((1,), ("data",)))
     keys, h_key, meta = net.fused_layout()
     out = sharded_fused_lookup_ref(q, keys, h_key, meta, 8, h_repo=9.0)
     np.testing.assert_array_equal(np.asarray(out[2]), 0)    # level
@@ -280,6 +293,7 @@ def run_in_subprocess(body: str):
         assert jax.device_count() == 8
         from repro.core.simcache import (REPO_LEVEL, SENTINEL_COORD,
                                          CacheLevel, SimCacheNetwork)
+        from repro.launch.mesh import make_mesh
 
         def make_net(seed, sizes, hs, h_repo, metric="l2", gamma=1.0,
                      d=6, empty=(), **kw):
@@ -328,7 +342,7 @@ def test_eight_way_mesh_differential_subprocess():
     levels with sentinels split across shards, B=1, and a multi-tile
     batch."""
     run_in_subprocess("""
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         cases = [
             # uneven: K=17 pads to 24, shards hold 3 keys, 7 of them pad
             (0, [5, 9, 3], [0.0, 0.5, 1.0], 2.0, "l2", 1.0, (), 23),
@@ -365,7 +379,7 @@ def test_eight_way_mesh_differential_subprocess():
 
 def test_eight_way_ties_and_staleness_subprocess():
     run_in_subprocess("""
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         # exact tie across shards: identical key at slot 5 of two levels
         # with equal h (concatenated indices 5 and 13 → shards 2 and 6);
         # deterministic winner = lower shard = lower level

@@ -17,6 +17,7 @@ from repro import tracecount
 from repro.configs.registry import get_smoke_config
 from repro.core import catalog as catalog_api
 from repro.core import demand as demand_api
+from repro.launch.mesh import make_mesh
 from repro.models import model as model_api
 from repro.serve import (EngineConfig, SimCacheEngine, StreamDriver,
                          StreamSpec, bucket_size)
@@ -226,7 +227,7 @@ def test_atomic_swap_differential():
 @pytest.mark.skipif(jax.device_count() < 8,
                     reason="needs 8 devices (ci.sh pass 2)")
 def test_atomic_swap_differential_8way():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     _swap_differential(sharded=True, mesh=mesh)
 
 
@@ -242,7 +243,7 @@ def test_atomic_swap_differential_warmstart():
 @pytest.mark.skipif(jax.device_count() < 8,
                     reason="needs 8 devices (ci.sh pass 2)")
 def test_atomic_swap_differential_warmstart_8way():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     _swap_differential(sharded=True, mesh=mesh, warm_start=True,
                        warm_polish_iters=128)
 
