@@ -1,0 +1,10 @@
+"""``host_copy_ms`` batch by batch: the median over the traced
+``engine.serve`` spans of the device-idle milliseconds inside the
+batch's ``serve.fetch_lookup`` and ``serve.fetch_prefill`` spans. A
+pause of the process that lands in one batch moves the mean and not
+this. None where the program has no such spans."""
+from host_copy_ms import PHASES, idle_ms_median
+
+
+def read(ctx):
+    return idle_ms_median(ctx, PHASES)

@@ -1,0 +1,3 @@
+"""``host_loop_p50_ms`` in an open-loop cell, where it moves the latency
+tail."""
+from host_loop_p50_ms import read  # noqa: F401

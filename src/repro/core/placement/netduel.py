@@ -472,6 +472,7 @@ class DuelPlane:
         self.served_cost = 0.0
         self._args = _scan_args(dinst)
 
+    @tracecount.spanned("DuelPlane.observe")
     def observe(self, objs: np.ndarray, ings: np.ndarray | None = None,
                 b1_ext: np.ndarray | None = None,
                 n_valid: int | None = None) -> bool:

@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracecount
 from repro.kernels.knn import (default_policy, fused_lookup,
                                mesh_axes_size, nearest_approximizer,
                                pad_to_shards, pruned_fused_lookup,
@@ -298,6 +299,7 @@ class SimCacheNetwork:
                                           n_probes)
         return self._tables[memo_key]
 
+    @tracecount.spanned("simcache.lookup")
     def lookup(self, queries: jax.Array, prune: str | None = None,
                verify: bool = False, quantize: bool = False,
                top_t: int | None = None) -> LookupResult:

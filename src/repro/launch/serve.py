@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracecount
 from repro.configs.base import ArchConfig
 from repro.configs.registry import get_smoke_config, list_archs
 from repro.core import catalog as catalog_api
@@ -121,6 +122,23 @@ def run_streaming(eng, cat, args) -> None:
           f"{st.placement_events}; placement v{eng.placement.version}")
 
 
+def print_phase_table() -> None:
+    """The program's phase table (``repro.tracecount``): one line per
+    span (calls, mean and max wall milliseconds), then one per counter
+    (traces of a jitted body, or units of work of the served path:
+    batches, requests, lookup and prefill rows, copies and their
+    bytes)."""
+    summary = tracecount.summary()
+    print(f"[serve] {'phase':<22} {'count':>7} {'mean ms':>10} "
+          f"{'max ms':>10}")
+    for name, row in sorted(summary["spans"].items()):
+        print(f"[serve] {name:<22} {row['count']:>7} "
+              f"{row['mean_ms']:>10.3f} {row['max_ms']:>10.3f}")
+    print(f"[serve] {'counter':<22} {'count':>10}")
+    for name, n in sorted(summary["counts"].items()):
+        print(f"[serve] {name:<22} {n:>10}")
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_archs())
@@ -189,6 +207,7 @@ def main() -> None:
     s = eng.stats
     print(f"[serve] {s.n_requests} requests, hit-rate {s.hit_rate:.1%}, "
           f"mean cost {s.mean_cost:.2f} ms, model batches {s.model_calls}")
+    print_phase_table()
 
 
 if __name__ == "__main__":

@@ -124,6 +124,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracecount
 from repro.configs.base import ArchConfig
 from repro.core import demand as demand_api
 from repro.core.analysis import surrogate_cost
@@ -382,8 +383,9 @@ class SimCacheEngine:
     def prefill(self, tokens: jnp.ndarray) -> jax.Array:
         """Run the repository model on a (B, S) token batch; returns the
         (B, vocab) logits of the last position."""
-        logits, _ = self._prefill(self.params, {"tokens": tokens})
-        return logits[:, -1, :]
+        with tracecount.span("engine.prefill"):
+            logits, _ = self._prefill(self.params, {"tokens": tokens})
+            return logits[:, -1, :]
 
     # ------------------------------------------------------- calibration
     def calibrate(self, sample_prompt: jnp.ndarray, n: int = 3) -> float:
@@ -459,6 +461,7 @@ class SimCacheEngine:
             return None
         return self.lookup_shards.control_plane_args(self.ecfg.sharded)
 
+    @tracecount.spanned("engine.solve")
     def _solve(self, inst: Instance, algo: str, device: bool,
                shard: bool = True) -> tuple[np.ndarray, float]:
         """Run the offline solver on one observed instance; returns the
@@ -644,9 +647,9 @@ class SimCacheEngine:
         if pend is None:
             return False
         slots, inst, pred, surrogate_now = pend
-        t0 = time.perf_counter()
-        self._install(slots, inst)
-        stall = time.perf_counter() - t0
+        with tracecount.span("engine.swap") as sp:
+            self._install(slots, inst)
+        stall = sp.ns * 1e-9
         self.swap_stall_s += stall
         self.max_swap_stall_s = max(self.max_swap_stall_s, stall)
         self.last_swap_stall_s = stall
@@ -658,6 +661,7 @@ class SimCacheEngine:
         self._in_flight = False
         return True
 
+    @tracecount.spanned("engine.install")
     def _rebuild_simcache(self, slots: np.ndarray,
                           slot_cache: np.ndarray | None = None) -> None:
         """(Re)build the runtime lookup network from an allocation and
@@ -706,19 +710,37 @@ class SimCacheEngine:
         (padding masked out of every stat and the duel trajectory), so a
         stream of mixed batch sizes compiles each entry point once per
         bucket instead of once per size.
+
+        The call is the ``engine.serve`` span (stats: ``batch``, the
+        process's served-batch index, and ``n``); its phases are spans
+        nested inside it (see ``repro.tracecount``), and its duration is
+        the batch's entry in ``ServeStats.batch_latencies_ms``.
         """
-        t_batch0 = time.perf_counter()
         request_ids = np.asarray(request_ids)
         n = len(request_ids)
-        if ingress_ids is None:
-            ingress_ids = np.zeros(n, dtype=np.int64)
-        else:
-            ingress_ids = np.asarray(ingress_ids, dtype=np.int64)
-        # np.add.at, not fancy-indexed +=: a batch with the same object
-        # twice must count twice (the += form collapses duplicates and
-        # undercounts exactly the hot objects of a skewed trace)
-        np.add.at(self.counts, (ingress_ids, request_ids), 1.0)
-        self.stats.n_requests += n
+        with tracecount.span("engine.serve",
+                             batch=tracecount.get("serve.batches"),
+                             n=n) as sp:
+            out = self._serve(request_ids, prompts, ingress_ids)
+        self.stats.batch_latencies_ms.append(sp.ns * 1e-6)
+        return out, self.stats
+
+    def _serve(self, request_ids: np.ndarray, prompts: jnp.ndarray,
+               ingress_ids: np.ndarray | None) -> list:
+        n = len(request_ids)
+        with tracecount.span("serve.demand"):
+            if ingress_ids is None:
+                ingress_ids = np.zeros(n, dtype=np.int64)
+            else:
+                ingress_ids = np.asarray(ingress_ids, dtype=np.int64)
+            # np.add.at, not fancy-indexed +=: a batch with the same
+            # object twice must count twice (the += form collapses
+            # duplicates and undercounts exactly the hot objects of a
+            # skewed trace)
+            np.add.at(self.counts, (ingress_ids, request_ids), 1.0)
+            self.stats.n_requests += n
+            tracecount.add("serve.batches")
+            tracecount.add("serve.requests", n)
         out: list = [None] * n
         bucket = self.ecfg.bucket
 
@@ -736,24 +758,34 @@ class SimCacheEngine:
         elif self.simcache is None:
             miss_idx = np.arange(n)
         else:
-            q = jnp.asarray(self.coords[request_ids])
-            if bucket:
-                q = _pad_rows(q, bucket_size(n, self.ecfg.min_bucket))
+            with tracecount.span("serve.queries"):
+                q = jnp.asarray(self.coords[request_ids])
+                if bucket:
+                    q = _pad_rows(q, bucket_size(n, self.ecfg.min_bucket))
+            tracecount.add("lookup.rows", q.shape[0])
+            tracecount.add("lookup.rows_valid", n)
             res = self.simcache.lookup(q, prune=self.ecfg.prune,
                                        verify=self.ecfg.verify,
                                        quantize=self.ecfg.quantize)
-            # slice the valid prefix before any accounting: padded rows
-            # never touch stats, responses, or the demand window
-            hits = np.asarray(res.hit)[:n]
-            payloads = np.asarray(res.payload)[:n]
-            full_cost = np.asarray(res.cost)          # bucket shape
-            self.stats.total_cost += float(np.sum(full_cost[:n]))
-            self.stats.total_approx_cost += float(
-                np.sum(np.asarray(res.approx_cost)[:n]))
-            for i in np.nonzero(hits)[0]:
-                out[i] = self.responses.get(int(payloads[i]))
-            self.stats.n_hits += int(hits.sum())
-            miss_idx = np.nonzero(~hits)[0]
+            with tracecount.span("serve.fetch_lookup"):
+                hit_b = np.asarray(res.hit)
+                payload_b = np.asarray(res.payload)
+                full_cost = np.asarray(res.cost)      # bucket shape
+                approx_b = np.asarray(res.approx_cost)
+            tracecount.add("serve.copies", 4)
+            tracecount.add("serve.copy_bytes", hit_b.nbytes
+                           + payload_b.nbytes + full_cost.nbytes
+                           + approx_b.nbytes)
+            with tracecount.span("serve.respond_hits"):
+                # slice the valid prefix before any accounting: padded
+                # rows never touch stats, responses, or the demand window
+                hits, payloads = hit_b[:n], payload_b[:n]
+                self.stats.total_cost += float(np.sum(full_cost[:n]))
+                self.stats.total_approx_cost += float(np.sum(approx_b[:n]))
+                for i in np.nonzero(hits)[0]:
+                    out[i] = self.responses.get(int(payloads[i]))
+                self.stats.n_hits += int(hits.sum())
+                miss_idx = np.nonzero(~hits)[0]
             if self.duel is not None:
                 # online control plane: observe the batch in one scan
                 # launch, priced by the costs the lookup just computed —
@@ -772,26 +804,32 @@ class SimCacheEngine:
         if len(miss_idx):
             # repository: run the model on the miss sub-batch (padded to
             # its own bucket so the prefill compiles per bucket too)
-            sel = prompts[jnp.asarray(miss_idx)]
-            if bucket:
-                sel = _pad_rows(sel, bucket_size(len(miss_idx),
-                                                 self.ecfg.min_bucket))
-            resp = np.asarray(jnp.argmax(self.prefill(sel), axis=-1))
+            with tracecount.span("serve.miss_gather"):
+                sel = prompts[jnp.asarray(miss_idx)]
+                if bucket:
+                    sel = _pad_rows(sel, bucket_size(len(miss_idx),
+                                                     self.ecfg.min_bucket))
+            tracecount.add("prefill.rows", sel.shape[0])
+            tracecount.add("prefill.rows_valid", len(miss_idx))
+            logits = self.prefill(sel)
+            with tracecount.span("serve.fetch_prefill"):
+                resp = np.asarray(jnp.argmax(logits, axis=-1))
+            tracecount.add("serve.copies")
+            tracecount.add("serve.copy_bytes", resp.nbytes)
             self.stats.model_calls += 1
             if self.routing is None and self.simcache is None:
                 # cold engine without a strategy plane: repository cost
                 # per miss (the routing plane already counted dec.cost)
                 self.stats.total_cost += self.ecfg.h_model * len(miss_idx)
-            for j, i in enumerate(miss_idx):
-                rid = int(request_ids[i])
-                self.responses[rid] = resp[j:j + 1]
-                out[i] = resp[j:j + 1]
+            with tracecount.span("serve.respond_misses"):
+                for j, i in enumerate(miss_idx):
+                    rid = int(request_ids[i])
+                    self.responses[rid] = resp[j:j + 1]
+                    out[i] = resp[j:j + 1]
         if route_dec is not None:
             # fill hits AFTER the miss prefill: a request can hit a key
             # an earlier miss of this very batch just inserted, whose
             # response only exists once the model ran
             for i in np.nonzero(route_dec.hit)[0]:
                 out[i] = self.responses.get(int(route_dec.payload[i]))
-        self.stats.batch_latencies_ms.append(
-            (time.perf_counter() - t_batch0) * 1e3)
-        return out, self.stats
+        return out
