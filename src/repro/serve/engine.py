@@ -24,7 +24,10 @@ Batch bucketing (``EngineConfig.bucket``, default on): every served
 batch is padded up to a power-of-two bucket (≥ ``min_bucket``) before
 touching a jitted entry point — the fused lookup, the duel scan
 (``DuelPlane.observe(n_valid=…)``), and the miss-prefill each compile
-once per *bucket*, not once per distinct batch size. Padding rows are
+once per *bucket*, not once per distinct batch size. The miss prefill
+pads less: its sub-batch runs as power-of-two pieces no smaller than
+``PIECE_TOKENS`` tokens (``prefill_pieces``), each a bucket shape, so
+~44 misses run as 32 + 16 rows rather than as 64. Padding rows are
 masked everywhere: they never enter ``counts``, ``ServeStats``, the
 duel trajectory (bit-identical to the unpadded one — the masked-scan
 contract of core/placement/netduel.py), or the responses returned.
@@ -149,6 +152,48 @@ def bucket_size(n: int, lo: int = 8) -> int:
     while m < n:
         m <<= 1
     return m
+
+
+# Tokens a prefill piece needs before one pass over the bf16 weights
+# stops being bound by HBM on a TPU v5e: a token costs 2 FLOP per 2-byte
+# weight, and the chip's ridge is 197e12 / 819e9 ≈ 240 FLOP per byte.
+PIECE_TOKENS = 256
+
+
+def piece_floor(seq: int, lo: int = 8) -> int:
+    """Fewest rows of a prefill piece of ``seq``-token prompts: the
+    smallest bucket (≥ ``lo``) that holds ``PIECE_TOKENS`` tokens."""
+    return bucket_size(-(-PIECE_TOKENS // seq), lo)
+
+
+def prefill_pieces(n: int, seq: int, lo: int = 8) -> list[int]:
+    """Row counts, largest first, of the prefills that run a miss batch
+    of ``n`` prompts of ``seq`` tokens. The rows are rounded up to a
+    multiple of the piece floor and split into distinct powers of two;
+    where that is not fewer rows than the batch's bucket, the bucket runs
+    as one piece. So a plan never runs more rows than
+    ``bucket_size(n, lo)``, and every piece is a bucket shape the prefill
+    already compiles. Of several pieces the first is half the bucket."""
+    b = bucket_size(n, lo)
+    f = piece_floor(seq, lo)
+    r = -(-n // f) * f
+    if r >= b:
+        return [b]
+    return [1 << i for i in range(r.bit_length() - 1, -1, -1) if r >> i & 1]
+
+
+def _first_piece(logits, rows: int):
+    """A (rows, vocab) array: ``logits``' last position, then zeros."""
+    last = logits[:, -1, :]
+    return jnp.zeros((rows, last.shape[1]), last.dtype).at[
+        :last.shape[0]].set(last)
+
+
+def _next_piece(out, logits, at):
+    """``out`` with rows [at, at + len(logits)) set to ``logits``' last
+    position."""
+    return jax.lax.dynamic_update_slice_in_dim(out, logits[:, -1, :], at,
+                                               axis=0)
 
 
 def _pad_rows(x, m: int):
@@ -333,6 +378,9 @@ class SimCacheEngine:
         self.duel: DuelPlane | None = None                # online §5 plane
         self.placement_events = 0                         # duel churn count
         self._prefill = jax.jit(model_api.make_prefill(cfg))
+        # (output bucket, prompt length) → the programs that lay the
+        # pieces of a split prefill into the bucket's logits
+        self._assembly: dict[tuple[int, int], tuple] = {}
         self.placement = PlacementBuffer()                # active data plane
         # background-refresh control: the worker thread solves, the
         # serving thread swaps; _pending crosses under _refresh_lock
@@ -382,10 +430,63 @@ class SimCacheEngine:
     # -------------------------------------------------------- repository
     def prefill(self, tokens: jnp.ndarray) -> jax.Array:
         """Run the repository model on a (B, S) token batch; returns the
-        (B, vocab) logits of the last position."""
+        logits of the last position.
+
+        With ``EngineConfig.bucket``, B rows that are exactly a plan of
+        several pieces (``prefill_pieces(B, S, min_bucket)``, as ``serve``
+        pads a miss batch) run as those power-of-two prefills, back to
+        back with no host sync, and come back as one (bucket_size(B),
+        vocab) array whose first B rows are the pieces' rows in order.
+        Any other batch is one prefill with (B, vocab) logits; at a
+        bucket shape it also compiles, the first time, the programs that
+        lay pieces into that bucket, so a split batch of host (NumPy)
+        tokens compiles nothing; device tokens add an eager slice per
+        piece shape."""
         with tracecount.span("engine.prefill"):
-            logits, _ = self._prefill(self.params, {"tokens": tokens})
-            return logits[:, -1, :]
+            b, seq = tokens.shape
+            lo = self.ecfg.min_bucket
+            pieces = prefill_pieces(b, seq, lo) if self.ecfg.bucket else [b]
+            if len(pieces) == 1 or sum(pieces) != b:
+                logits, _ = self._prefill(self.params, {"tokens": tokens})
+                if self.ecfg.bucket and b == bucket_size(b, lo):
+                    self._assemble(b, logits)
+                return logits[:, -1, :]
+            out, at = None, 0
+            for p in pieces:
+                logits, _ = self._prefill(self.params,
+                                          {"tokens": tokens[at:at + p]})
+                first, rest = self._assemble(bucket_size(b, lo), logits)
+                out = first(logits) if out is None \
+                    else rest[p](out, logits, np.int32(at))
+                at += p
+            return out
+
+    def _assemble(self, b: int, logits: jax.Array) -> tuple:
+        """(first, {rows: next}) for output bucket ``b`` at the prompt
+        length of ``logits`` (a prefill's (rows, S, vocab) output),
+        compiled on first use: ``first`` lays the first piece, b/2 rows,
+        into a new (b, vocab) array; ``next[p]`` lays a p-row piece at a
+        traced offset, in place, for each p from the piece floor to b/4.
+        Nothing where no plan splits a batch of bucket ``b``."""
+        seq, vocab = logits.shape[1:]
+        key = (b, seq)
+        if key not in self._assembly:
+            f = piece_floor(seq, self.ecfg.min_bucket)
+            first, rest = None, {}
+            if b // 2 >= f:
+                def piece(p):
+                    return jax.ShapeDtypeStruct((p, seq, vocab),
+                                                logits.dtype)
+                first = jax.jit(_first_piece, static_argnums=1).lower(
+                    piece(b // 2), b).compile()
+                out = jax.ShapeDtypeStruct((b, vocab), logits.dtype)
+                at = jax.ShapeDtypeStruct((), jnp.int32)
+                rest = {p: jax.jit(_next_piece, donate_argnums=0).lower(
+                            out, piece(p), at).compile()
+                        for p in (f << k for k in range(
+                            (b // 4 // f).bit_length()))}
+            self._assembly[key] = first, rest
+        return self._assembly[key]
 
     # ------------------------------------------------------- calibration
     def calibrate(self, sample_prompt: jnp.ndarray, n: int = 3) -> float:
@@ -705,11 +806,12 @@ class SimCacheEngine:
         ``ingress_ids`` says where each request entered the network
         (None → ingress 0, the single-ingress hierarchy's only row).
 
-        With ``EngineConfig.bucket`` the lookup, the duel observation and
-        the miss-prefill all run at the batch's power-of-two bucket shape
-        (padding masked out of every stat and the duel trajectory), so a
-        stream of mixed batch sizes compiles each entry point once per
-        bucket instead of once per size.
+        With ``EngineConfig.bucket`` the lookup and the duel observation
+        run at the batch's power-of-two bucket shape (padding masked out
+        of every stat and the duel trajectory), and the miss prefill as
+        the power-of-two pieces of ``prefill_pieces``, so a stream of
+        mixed batch sizes compiles each entry point once per bucket
+        instead of once per size.
 
         The call is the ``engine.serve`` span (stats: ``batch``, the
         process's served-batch index, and ``n``); its phases are spans
@@ -802,15 +904,20 @@ class SimCacheEngine:
                         self.request_refresh()
 
         if len(miss_idx):
-            # repository: run the model on the miss sub-batch (padded to
-            # its own bucket so the prefill compiles per bucket too)
+            # repository: run the model on the miss sub-batch, padded to
+            # its piece plan: power-of-two prefills that compile per
+            # bucket, with no more rows than the sub-batch's own bucket
             with tracecount.span("serve.miss_gather"):
                 sel = prompts[jnp.asarray(miss_idx)]
+                pieces = [len(miss_idx)]
                 if bucket:
-                    sel = _pad_rows(sel, bucket_size(len(miss_idx),
-                                                     self.ecfg.min_bucket))
+                    pieces = prefill_pieces(len(miss_idx), sel.shape[1],
+                                            self.ecfg.min_bucket)
+                    sel = _pad_rows(sel, sum(pieces))
             tracecount.add("prefill.rows", sel.shape[0])
             tracecount.add("prefill.rows_valid", len(miss_idx))
+            tracecount.add("prefill.batches")
+            tracecount.add("prefill.pieces", len(pieces))
             logits = self.prefill(sel)
             with tracecount.span("serve.fetch_prefill"):
                 resp = np.asarray(jnp.argmax(logits, axis=-1))

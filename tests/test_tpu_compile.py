@@ -12,7 +12,9 @@ chip of a ``v5e:2x2`` topology at the size the serving path runs it:
 * the placement gain kernel at R = O = 8192;
 * flash attention at granite-3-2b's head widths;
 * the granite-3-2b prefill at its published widths with bf16
-  parameters, which must fit the chip's 16 GB.
+  parameters, which must fit the chip's 16 GB;
+* the programs that lay a split miss prefill's pieces into its
+  bucket's logits, the later pieces in place.
 
 The topology is described inside a module fixture, never while the
 module is imported: only one process may load the TPU library, and the
@@ -123,3 +125,23 @@ def test_granite_prefill_fits_one_chip_with_bf16_params(one_chip):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, used
+
+
+@pytest.mark.parametrize("rows,piece", [(64, 32), (64, 8), (1024, 16)])
+def test_prefill_pieces_assemble_in_place(one_chip, rows, piece):
+    """The programs that lay a split prefill's pieces into its bucket's
+    logits, at granite-3-2b's vocabulary in bf16: the first piece into a
+    new (rows, vocab) array, a later one at a traced offset into the
+    donated array, which the output then aliases."""
+    from repro.serve.engine import _first_piece, _next_piece
+    vocab, seq = 49155, 128 if rows == 64 else 16
+    logits = _spec(one_chip, (piece, seq, vocab), jnp.bfloat16)
+    out = _spec(one_chip, (rows, vocab), jnp.bfloat16)
+    first = jax.jit(_first_piece, static_argnums=1).lower(
+        _spec(one_chip, (rows // 2, seq, vocab), jnp.bfloat16),
+        rows).compile()
+    assert first.out_info.shape == (rows, vocab)
+    nxt = jax.jit(_next_piece, donate_argnums=0).lower(
+        out, logits, _spec(one_chip, (), jnp.int32)).compile()
+    # the aliased buffer is the whole output, padded to the chip's tiles
+    assert nxt.memory_analysis().alias_size_in_bytes >= rows * vocab * 2

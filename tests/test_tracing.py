@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro import tracecount
-from repro.serve import bucket_size
+from repro.serve import bucket_size, prefill_pieces
 
 from test_streaming import make_engine, mixed_batches
 
@@ -162,8 +162,11 @@ def test_prefill_counters_count_the_bucket_and_the_misses(warm_engine):
     misses = 40 - (eng.stats.n_hits - hits0)
     assert 0 < misses and eng.stats.n_requests - n_req0 == 40
     lo = eng.ecfg.min_bucket
-    assert s.delta("prefill.rows") == bucket_size(misses, lo)
+    pieces = prefill_pieces(misses, prompts.shape[1], lo)
+    assert s.delta("prefill.rows") == sum(pieces)
     assert s.delta("prefill.rows_valid") == misses
+    assert s.delta("prefill.batches") == 1
+    assert s.delta("prefill.pieces") == len(pieces)
     assert s.delta("lookup.rows") == bucket_size(40, lo) == 64
     assert s.delta("lookup.rows_valid") == 40
     assert s.delta("serve.batches") == 1
