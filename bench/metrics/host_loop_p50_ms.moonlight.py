@@ -1,0 +1,2 @@
+"""``host_loop_p50_ms`` in the Moonlight cell."""
+from host_loop_p50_ms import read  # noqa: F401

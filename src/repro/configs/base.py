@@ -34,6 +34,22 @@ class ArchConfig:
     dense_ff: int = 0            # d_ff of the dense MLP on non-MoE layers (hybrid MoE)
     moe_group_size: int = 512    # tokens per dispatch group (einsum mode)
     moe_dispatch: str = "einsum"  # einsum (GShard baseline) | gather (opt)
+    moe_ff: int = 0              # routed expert width (0 ⇒ d_ff)
+    shared_ff: int = 0           # width of the shared experts' one SwiGLU (0 ⇒ none)
+    n_dense_layers: int = 0      # leading d_ff layers before the block pattern
+    # routing rule of the model: "softmax" top-k, or DeepSeek-V3's
+    # "sigmoid" scores, chosen by score + a learned correction bias,
+    # weighted by the chosen scores normalised to one
+    moe_router: str = "softmax"
+    moe_routed_scale: float = 1.0  # routed output × this (routed_scaling_factor)
+    # the routed experts this chip holds, (first, count), of the
+    # router's moe_experts; () holds them all
+    experts_held: tuple = ()
+
+    # --- multi-head latent attention (DeepSeek-V2/V3): 0 ⇒ GQA ---
+    kv_lora_rank: int = 0        # normed latent that keys and values expand from
+    qk_rope_dim: int = 0         # one rotary key shared by all heads; head_dim
+    #                              is the per-head rope-free q/k and v width
 
     # --- hybrid (jamba): attention on every `attn_every`-th layer, rest Mamba
     attn_every: int = 0          # 0 ⇒ all layers are attention
@@ -79,6 +95,11 @@ class ArchConfig:
             object.__setattr__(self, "ssm_dt_rank",
                                ceil_to(self.d_model, 16) // 16)
         assert self.n_heads % self.n_kv_heads == 0
+        object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        first, n = self.held
+        if first < 0 or first + n > self.moe_experts:
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"range of the {self.moe_experts} experts")
 
     # ------------------------------------------------------------- derived
     @property
@@ -95,7 +116,22 @@ class ArchConfig:
             return True
         return i % self.attn_every == 0
 
+    @property
+    def held(self) -> tuple:
+        """(first, count) of the routed experts this chip holds."""
+        return self.experts_held or (0, self.moe_experts)
+
+    @property
+    def expert_ff(self) -> int:
+        return self.moe_ff or self.d_ff
+
+    @property
+    def pattern_layers(self) -> int:
+        """Layers of the scanned block pattern, after the dense lead."""
+        return self.n_layers - self.n_dense_layers
+
     def is_moe_layer(self, i: int) -> bool:
+        """Whether layer ``i`` of the block pattern is a MoE layer."""
         if self.moe_experts == 0:
             return False
         return i % self.moe_every == self.moe_offset

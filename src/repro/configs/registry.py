@@ -13,7 +13,7 @@ from repro.configs.base import ArchConfig
 from repro.configs import (jamba_1_5_large_398b, deepseek_67b, granite_3_2b,
                            deepseek_coder_33b, phi3_medium_14b,
                            granite_moe_3b_a800m, dbrx_132b, xlstm_350m,
-                           whisper_small, qwen2_vl_7b)
+                           whisper_small, qwen2_vl_7b, moonlight_16b_a3b)
 
 _MODULES = {
     "jamba-1.5-large-398b": jamba_1_5_large_398b,
@@ -26,6 +26,7 @@ _MODULES = {
     "xlstm-350m": xlstm_350m,
     "whisper-small": whisper_small,
     "qwen2-vl-7b": qwen2_vl_7b,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
 }
 
 

@@ -49,6 +49,17 @@ def apply_rope(x: jax.Array, positions: jax.Array,
     return out.astype(x.dtype)
 
 
+def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
+                           theta: float = 1e4) -> jax.Array:
+    """RoPE on DeepSeek-V3's layout: the interleaved pairs (x0, x1),
+    (x2, x3), … are de-interleaved to (x0, x2, …, x1, x3, …), then
+    rotated as ``apply_rope`` does; the result stays de-interleaved, as
+    in the published modelling code (q and k alike, so q·k is that of
+    the pairs)."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return apply_rope(x, positions, theta)
+
+
 def apply_mrope(x: jax.Array, positions: jax.Array, sections: tuple,
                 theta: float = 1e4) -> jax.Array:
     """Multimodal RoPE (Qwen2-VL): ``positions`` is (3, B, S) —
@@ -78,11 +89,11 @@ def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   kv_len: jax.Array | None = None) -> jax.Array:
     """Grouped-query attention.
 
-    q: (B, Sq, H, Dh); k, v: (B, Skv, KH, Dh) with H % KH == 0.
+    q, k: (B, Sq|Skv, H|KH, Dh); v: (B, Skv, KH, Dv) with H % KH == 0.
     ``q_offset`` positions the query block inside the kv timeline (decode:
     q_offset = current length − Sq). ``kv_len`` masks out cache slots
     beyond the valid length (decode with a statically-shaped cache).
-    Returns (B, Sq, H, Dh). Softmax in f32.
+    Returns (B, Sq, H, Dv). Softmax in f32.
     """
     B, Sq, H, Dh = q.shape
     Skv, KH = k.shape[1], k.shape[2]
@@ -100,7 +111,7 @@ def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         scores = jnp.where(tpos < kv_len, scores, neg)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v.astype(jnp.float32))
-    return out.reshape(B, Sq, H, Dh).astype(q.dtype)
+    return out.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 # ------------------------------------------------------------------- MLP

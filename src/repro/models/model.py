@@ -72,6 +72,17 @@ def make_prefill(cfg: ArchConfig, shard: ShardPolicy = NO_SHARD) -> Callable:
     return prefill
 
 
+def make_prefill_last(cfg: ArchConfig, shard: ShardPolicy = NO_SHARD
+                      ) -> Callable:
+    """(params, batch) → ((B, vocab) logits of the last position, MoE row
+    counts (MoE layers, held experts) or None): a miss's prefill, with
+    no caches and the head at one position (``transformer.forward_last``).
+    Jitted, it is the program ``jit_prefill``."""
+    def prefill(params, batch):
+        return transformer.forward_last(cfg, params, batch, shard)
+    return prefill
+
+
 def make_serve_step(cfg: ArchConfig, shard: ShardPolicy = NO_SHARD
                     ) -> Callable:
     """One decode step: (params, tokens (B,1), caches, pos) →
@@ -121,12 +132,17 @@ def greedy_generate(cfg: ArchConfig, params: dict, prompt: jax.Array,
 
 def _pad_caches(cfg: ArchConfig, caches: Any, max_len: int) -> Any:
     """Pad prefill KV caches along the sequence axis to ``max_len``.
-    Only the self-attention "k"/"v" leaves grow; SSM/xLSTM states and
-    cross-attention caches are fixed-size."""
+    Only the self-attention "k"/"v" (latent attention: "ckv"/"kpe")
+    leaves grow; SSM/xLSTM states and cross-attention caches are
+    fixed-size."""
     from repro.models.transformer import _kv_quant
 
     def pad_entry(block_cache: dict) -> dict:
         out = dict(block_cache)
+        for key in ("ckv", "kpe"):         # latent attention: (n, B, S, w)
+            if key in out and out[key].shape[2] < max_len:
+                out[key] = jnp.pad(out[key], ((0, 0), (0, 0), (
+                    0, max_len - out[key].shape[2]), (0, 0)))
         for key in ("k", "v"):
             if key not in out:
                 continue

@@ -17,6 +17,15 @@ GShard-style static-capacity dispatch, adapted for TPU SPMD:
 Expert-axis sharding requires E % mesh_model == 0; the resolver
 (launch/sharding.py) otherwise falls back to within-expert d_ff sharding
 (granite-moe: 40 experts, d_ff=512).
+
+Serving (prefill and decode) drops nothing: ``moe_dropless`` sorts the
+token-expert assignments by expert and runs the experts as grouped
+matmuls (``jax.lax.ragged_dot``) over per-expert row counts, so a
+token's output never depends on which other tokens shared its batch.
+Its layer holds a contiguous range of the router's experts, as a chip
+of an expert-parallel deployment does: it routes over all of them and
+adds only the held experts' part; assignments to the others add
+nothing.
 """
 from __future__ import annotations
 
@@ -31,18 +40,35 @@ def _capacity(tg: int, k: int, e: int, cf: float) -> int:
     return max(c, 1)
 
 
-def _route(xt_2d: jax.Array, router: jax.Array, topk: int):
-    """Shared routing: top-k gates + Switch aux loss. xt_2d: (T, D)."""
+def route(xt_2d: jax.Array, router: jax.Array, topk: int,
+          rule: str = "softmax", bias: jax.Array | None = None,
+          scale: float = 1.0):
+    """Top-k gates + Switch aux loss. xt_2d: (T, D) → (weights (T, K)
+    f32, expert ids (T, K), aux). ``softmax``: the top-k of the softmax,
+    renormalised. ``sigmoid`` (DeepSeek-V3): sigmoid scores; the choice
+    is the top-k of score + ``bias``, the weights the chosen scores
+    normalised to one; both rules then × ``scale``. The router's matmul
+    runs in f32 at full precision, so bf16 rounding of the product does
+    not move a choice."""
     E = router.shape[1]
     logits = jnp.einsum("td,de->te", xt_2d.astype(jnp.float32),
-                        router.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                 # (T, E)
-    gate_vals, gate_idx = jax.lax.top_k(probs, topk)        # (T, K)
-    gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True) + 1e-9)
-    me = jnp.mean(probs, axis=0)
+                        router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if rule == "sigmoid":
+        probs = jax.nn.sigmoid(logits)                      # (T, E)
+        _, gate_idx = jax.lax.top_k(probs + bias.astype(jnp.float32), topk)
+        gate_vals = jnp.take_along_axis(probs, gate_idx, -1)
+        gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True)
+                                 + 1e-20)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, topk)    # (T, K)
+        gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True)
+                                 + 1e-9)
+    me = jnp.mean(probs / jnp.sum(probs, -1, keepdims=True), axis=0)
     fe = jnp.mean(jax.nn.one_hot(gate_idx[..., 0], E), axis=0)
     aux = E * jnp.sum(me * fe)
-    return gate_vals, gate_idx, aux
+    return gate_vals * scale, gate_idx, aux
 
 
 def _positions_in_expert(gate_idx: jax.Array, E: int, cap: int):
@@ -67,7 +93,7 @@ def _positions_in_expert(gate_idx: jax.Array, E: int, cap: int):
 
 
 def _moe_einsum(x, router, we_gate, we_up, we_down, topk, capacity_factor,
-                group_size, shard):
+                group_size, shard, rule):
     """GShard-style grouped one-hot dispatch (the TPU-classic baseline).
 
     The dispatch/combine einsums cost T·E_loc·Cg·D each — under expert
@@ -84,7 +110,8 @@ def _moe_einsum(x, router, we_gate, we_up, we_down, topk, capacity_factor,
     Cg = _capacity(Tg, topk, E, capacity_factor)
     xt = x.reshape(G, Tg, D)
 
-    gate_vals, gate_idx, aux = _route(x.reshape(T, D), router, topk)
+    gate_vals, gate_idx, aux = route(x.reshape(T, D), router, topk,
+                                     **rule)
     gate_vals = gate_vals.reshape(G, Tg, -1)
     gate_idx = gate_idx.reshape(G, Tg, -1)
     pos, keep = _positions_in_expert(gate_idx, E, Cg)        # (G, Tg, K)
@@ -114,7 +141,7 @@ def _moe_einsum(x, router, we_gate, we_up, we_down, topk, capacity_factor,
 
 
 def _moe_gather(x, router, we_gate, we_up, we_down, topk, capacity_factor,
-                group_size, shard):
+                group_size, shard, rule):
     """Grouped gather/scatter dispatch (beyond-paper optimization, §Perf).
 
     Replaces the O(T·E·Cg·D) one-hot dispatch/combine einsums with
@@ -133,7 +160,8 @@ def _moe_gather(x, router, we_gate, we_up, we_down, topk, capacity_factor,
     Cg = _capacity(Tg, topk, E, capacity_factor)
     xt = x.reshape(G, Tg, D)
 
-    gate_vals, gate_idx, aux = _route(x.reshape(T, D), router, topk)
+    gate_vals, gate_idx, aux = route(x.reshape(T, D), router, topk,
+                                     **rule)
     gate_vals = gate_vals.reshape(G, Tg, -1)                 # (G, Tg, K)
     gate_idx = gate_idx.reshape(G, Tg, -1)
     pos, keep = _positions_in_expert(gate_idx, E, Cg)        # (G, Tg, K)
@@ -169,11 +197,52 @@ def _moe_gather(x, router, we_gate, we_up, we_down, topk, capacity_factor,
 def moe_mlp(x: jax.Array, router: jax.Array, we_gate: jax.Array,
             we_up: jax.Array, we_down: jax.Array, topk: int,
             capacity_factor: float = 1.25, group_size: int = 512,
-            dispatch: str = "einsum", shard=None
+            dispatch: str = "einsum", shard=None, **rule
             ) -> tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) → (y, aux_loss). Expert weights: (E, D, F)/(E, F, D)."""
+    """x: (B, S, D) → (y, aux_loss). Expert weights: (E, D, F)/(E, F, D),
+    one per router output. ``rule``: ``route``'s rule, bias and scale."""
+    if we_gate.shape[0] != router.shape[1]:
+        raise ValueError("the capacity dispatch needs every expert held; "
+                         "a chip's share of the experts runs dropless")
     if dispatch == "gather":
         return _moe_gather(x, router, we_gate, we_up, we_down, topk,
-                           capacity_factor, group_size, shard)
+                           capacity_factor, group_size, shard, rule)
     return _moe_einsum(x, router, we_gate, we_up, we_down, topk,
-                       capacity_factor, group_size, shard)
+                       capacity_factor, group_size, shard, rule)
+
+
+def moe_dropless(x: jax.Array, router: jax.Array, we_gate: jax.Array,
+                 we_up: jax.Array, we_down: jax.Array, topk: int,
+                 first: int = 0, **rule) -> tuple[jax.Array, jax.Array]:
+    """Every token's routed output from the experts held here, with no
+    capacity. x: (T, D); the expert weights hold experts
+    [first, first + n) of the router's. Returns (y (T, D), rows (n,)
+    int32: the assignments each held expert computed).
+
+    The T·K assignments are sorted by held expert (the others last),
+    their tokens gathered into that order, and each held expert's rows
+    run as one group of the grouped matmuls; rows past the held ones
+    belong to no group and are never read. Each token's k-th row is
+    gathered back (zero where its k-th choice is not held) and the rows
+    summed weighted by their gates, in f32, with the top-k axis leading
+    so that no (T, K, D) relayout is made. The shapes depend on T alone,
+    so one program serves every routing."""
+    T, D = x.shape
+    n = we_gate.shape[0]
+    gate, idx, _ = route(x, router, topk, **rule)
+    local = idx - first                                      # (T, K)
+    held = (local >= 0) & (local < n)
+    key = jnp.where(held, local, n).reshape(-1)              # (T·K,)
+    order = jnp.argsort(key, stable=True)
+    rows = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+    xs = x[order // topk]                                    # (T·K, D)
+    dt = x.dtype
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, we_gate.astype(dt), rows)) \
+        * jax.lax.ragged_dot(xs, we_up.astype(dt), rows)
+    ys = jax.lax.ragged_dot(h, we_down.astype(dt), rows)     # (T·K, D)
+    # where each assignment's row went, (K, T); not held: out of range
+    at = jnp.where(held, jnp.argsort(order).reshape(T, topk), T * topk).T
+    yk = ys.at[at].get(mode="fill", fill_value=0)            # (K, T, D)
+    g = jnp.where(held, gate, 0.0).T                         # (K, T)
+    y = jnp.sum(g[:, :, None] * yk.astype(jnp.float32), axis=0)
+    return y.astype(dt), rows

@@ -50,7 +50,27 @@ class ParamSpec:
 
 
 # ------------------------------------------------------------ block kinds
+def mla_specs(cfg: ArchConfig) -> dict:
+    """Multi-head latent attention without a query latent: per-head
+    queries of head_dim + qk_rope_dim, one projection to the latent and
+    the shared rotary key, the latent's norm, its expansions to per-head
+    keys and values, and the output."""
+    d, h, dh = cfg.d_model, cfg.n_heads, cfg.head_dim
+    r, dr = cfg.kv_lora_rank, cfg.qk_rope_dim
+    return {
+        "attn_norm": ParamSpec((d,), ("embed",), "ones"),
+        "wq": ParamSpec((d, h, dh + dr), ("embed", "heads", "head_dim")),
+        "w_kv_a": ParamSpec((d, r + dr), ("embed", None)),
+        "kv_norm": ParamSpec((r,), (None,), "ones"),
+        "wk_b": ParamSpec((r, h, dh), (None, "heads", "head_dim")),
+        "wv_b": ParamSpec((r, h, dh), (None, "heads", "head_dim")),
+        "wo": ParamSpec((h, dh, d), ("heads", "head_dim", "embed")),
+    }
+
+
 def attn_specs(cfg: ArchConfig, cross: bool = False) -> dict:
+    if cfg.kv_lora_rank and not cross:
+        return mla_specs(cfg)
     d, h, kh, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     pfx = "x" if cross else ""
     out = {
@@ -90,14 +110,25 @@ def gelu_mlp_specs(cfg: ArchConfig, ff: int) -> dict:
 
 
 def moe_specs(cfg: ArchConfig) -> dict:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.moe_experts
-    return {
+    """The router over all ``moe_experts``, the weights of the experts
+    this chip holds, and the shared experts where the model has them."""
+    d, f, e = cfg.d_model, cfg.expert_ff, cfg.moe_experts
+    n = cfg.held[1]
+    out = {
         "moe_norm": ParamSpec((d,), ("embed",), "ones"),
         "router": ParamSpec((d, e), ("embed", None)),
-        "we_gate": ParamSpec((e, d, f), ("experts", "embed", "ff")),
-        "we_up": ParamSpec((e, d, f), ("experts", "embed", "ff")),
-        "we_down": ParamSpec((e, f, d), ("experts", "ff", "embed")),
+        "we_gate": ParamSpec((n, d, f), ("experts", "embed", "ff")),
+        "we_up": ParamSpec((n, d, f), ("experts", "embed", "ff")),
+        "we_down": ParamSpec((n, f, d), ("experts", "ff", "embed")),
     }
+    if cfg.moe_router == "sigmoid":
+        out["router_bias"] = ParamSpec((e,), (None,), "zeros")
+    if cfg.shared_ff:
+        s = cfg.shared_ff
+        out["ws_gate"] = ParamSpec((d, s), ("embed", "ff"))
+        out["ws_up"] = ParamSpec((d, s), ("embed", "ff"))
+        out["ws_down"] = ParamSpec((s, d), ("ff", "embed"))
+    return out
 
 
 def mamba_specs(cfg: ArchConfig) -> dict:
@@ -166,8 +197,15 @@ def block_pattern(cfg: ArchConfig) -> list[str]:
         mixer = "attn" if cfg.is_attn_layer(i) else "mamba"
         ffn = "moe" if cfg.is_moe_layer(i) else "mlp"
         pat.append(f"{mixer}+{ffn}")
-    assert cfg.n_layers % period == 0, (cfg.name, cfg.n_layers, period)
+    assert cfg.pattern_layers % period == 0, (cfg.name, cfg.n_layers, period)
     return pat
+
+
+LEAD = "attn+mlp"              # the kind of every leading dense layer
+
+
+def block_key(bi: int, kind: str, lead: bool = False) -> str:
+    return f"{'l' if lead else 'b'}{bi}_{kind.replace('+', '_')}"
 
 
 def block_specs(cfg: ArchConfig, kind: str) -> dict:
@@ -183,6 +221,13 @@ def block_specs(cfg: ArchConfig, kind: str) -> dict:
     else:
         ff = cfg.dense_ff if cfg.dense_ff else cfg.d_ff
         out.update(mlp_specs(cfg, ff))
+    return out
+
+
+def lead_specs(cfg: ArchConfig) -> dict:
+    """The leading dense layers: attention and a d_ff SwiGLU."""
+    out = dict(attn_specs(cfg))
+    out.update(mlp_specs(cfg, cfg.d_ff))
     return out
 
 
@@ -203,12 +248,14 @@ def param_schema(cfg: ArchConfig) -> dict:
         schema["lm_head"] = ParamSpec((d, vp), ("embed", "vocab"))
 
     pattern = block_pattern(cfg)
-    n_super = cfg.n_layers // len(pattern)
+    n_super = cfg.pattern_layers // len(pattern)
     blocks = {}
     for bi, kind in enumerate(pattern):
-        blocks[f"b{bi}_{kind.replace('+', '_')}"] = \
-            _stack(block_specs(cfg, kind), n_super)
+        blocks[block_key(bi, kind)] = _stack(block_specs(cfg, kind), n_super)
     schema["blocks"] = blocks
+    if cfg.n_dense_layers:
+        schema["lead_blocks"] = {block_key(0, LEAD, lead=True): _stack(
+            lead_specs(cfg), cfg.n_dense_layers)}
 
     if cfg.is_encdec:
         enc_blocks = {}
@@ -219,7 +266,7 @@ def param_schema(cfg: ArchConfig) -> dict:
         # decoder cross-attention, one per decoder layer
         cross = _stack(attn_specs(cfg, cross=True), n_super)
         for bi, kind in enumerate(pattern):
-            blocks[f"b{bi}_{kind.replace('+', '_')}"].update(cross)
+            blocks[block_key(bi, kind)].update(cross)
         schema["enc_blocks"] = enc_blocks
         schema["enc_final_norm"] = ParamSpec((d,), ("embed",), "ones")
     if cfg.frontend == "vision_stub":
@@ -278,7 +325,8 @@ def active_param_count(cfg: ArchConfig) -> int:
     """Parameters touched per token (MoE: only top-k experts active)."""
     total = param_count(cfg)
     if cfg.moe_experts:
-        per_expert = 3 * cfg.d_model * cfg.d_ff
-        n_moe = sum(1 for i in range(cfg.n_layers) if cfg.is_moe_layer(i))
-        total -= n_moe * (cfg.moe_experts - cfg.moe_topk) * per_expert
+        per_expert = 3 * cfg.d_model * cfg.expert_ff
+        n_moe = sum(1 for i in range(cfg.pattern_layers)
+                    if cfg.is_moe_layer(i))
+        total -= n_moe * (cfg.held[1] - cfg.moe_topk) * per_expert
     return total

@@ -1,14 +1,15 @@
 """Decoder-only LM stack covering the dense / moe / hybrid / ssm / vlm
-families: scan-over-super-blocks, GQA attention with pluggable sharding
-strategies, MoE, Mamba, xLSTM mixers, RoPE / M-RoPE.
+families: scan-over-super-blocks (after an optional group of leading
+dense layers), GQA or multi-head latent attention with pluggable
+sharding strategies, MoE, Mamba, xLSTM mixers, RoPE / M-RoPE.
 
 Modes:
   * "train"   — full-sequence teacher forcing, optional remat per block;
   * "prefill" — like train but returns the serving cache (KV / SSM state);
   * "decode"  — one token per call against a statically-shaped cache.
 
-The cache is a dict keyed like params["blocks"] with per-kind leaves
-stacked on the scanned super-block axis (see init_cache).
+The cache is a dict keyed like params["blocks"] (and "lead_blocks") with
+per-kind leaves stacked on the scanned layer axis (see init_cache).
 """
 from __future__ import annotations
 
@@ -19,8 +20,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.models import layers
-from repro.models.moe import moe_mlp
-from repro.models.schema import block_pattern
+from repro.models.moe import moe_dropless, moe_mlp
+from repro.models.schema import LEAD, block_key, block_pattern
 from repro.models.sharding_api import NO_SHARD, ShardPolicy
 from repro.models.ssm import mamba_mixer, mlstm_mixer, slstm_mixer
 
@@ -124,6 +125,45 @@ def _attention(cfg: ArchConfig, p: dict, x: jax.Array, positions, mode: str,
     return shard(y, ("batch", "seq", "embed")), new_cache
 
 
+def _mla_attention(cfg: ArchConfig, p: dict, x: jax.Array, positions,
+                   mode: str, cache: dict | None, pos, shard: ShardPolicy):
+    """Multi-head latent attention (DeepSeek-V3, no query latent).
+    Per position the cache holds the normed latent (``ckv``,
+    kv_lora_rank wide) and the roped key that all heads share (``kpe``,
+    qk_rope_dim wide); every head's key and value are expanded from the
+    latent. A head's query and key are its rope-free part (head_dim)
+    then the rotary part, so scores are scaled by that total width."""
+    dt = x.dtype
+    dh, r = cfg.head_dim, cfg.kv_lora_rank
+    h = layers.rms_norm(x, p["attn_norm"], cfg.norm_eps)
+    q = jnp.einsum("bsd,dhe->bshe", h, p["wq"].astype(dt))
+    q = jnp.concatenate([q[..., :dh], layers.apply_rope_interleaved(
+        q[..., dh:], positions, cfg.rope_theta)], axis=-1)
+    kv = jnp.einsum("bsd,de->bse", h, p["w_kv_a"].astype(dt))
+    ckv = layers.rms_norm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+    kpe = layers.apply_rope_interleaved(kv[..., None, r:], positions,
+                                        cfg.rope_theta)[:, :, 0]
+    new_cache, kv_len = {}, None
+    if mode == "decode":
+        upd = jax.lax.dynamic_update_slice
+        ckv = upd(cache["ckv"], ckv.astype(cache["ckv"].dtype), (0, pos, 0))
+        kpe = upd(cache["kpe"], kpe.astype(cache["kpe"].dtype), (0, pos, 0))
+        new_cache = {"ckv": ckv, "kpe": kpe}
+        ckv, kpe, kv_len = ckv.astype(dt), kpe.astype(dt), pos + 1
+    elif mode == "prefill":
+        new_cache = {"ckv": ckv, "kpe": kpe}
+    k = jnp.einsum("btr,rhe->bthe", ckv, p["wk_b"].astype(dt))
+    v = jnp.einsum("btr,rhe->bthe", ckv, p["wv_b"].astype(dt))
+    k = jnp.concatenate([k, jnp.broadcast_to(
+        kpe[:, :, None], k.shape[:3] + kpe.shape[-1:])], axis=-1)
+    q = shard(q, ("attn_batch", "attn_seq", "heads", "head_dim"))
+    out = layers.gqa_attention(q, k, v, causal=mode != "decode",
+                               kv_len=kv_len)
+    out = shard(out, ("attn_batch", "attn_seq", "heads", "head_dim"))
+    y = jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(dt))
+    return shard(y, ("batch", "seq", "embed")), new_cache
+
+
 def _mlp(cfg: ArchConfig, p: dict, x: jax.Array, shard: ShardPolicy):
     h = layers.rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     y = layers.swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
@@ -132,23 +172,38 @@ def _mlp(cfg: ArchConfig, p: dict, x: jax.Array, shard: ShardPolicy):
 
 def _moe(cfg: ArchConfig, p: dict, x: jax.Array, shard: ShardPolicy,
          mode: str):
+    """Routed experts plus the shared experts. Returns (y, aux loss,
+    rows each held expert computed or None). Training dispatches at the
+    configured capacity; serving (prefill, decode) drops nothing, so a
+    token's output never depends on the other tokens of its batch."""
     h = layers.rms_norm(x, p["moe_norm"], cfg.norm_eps)
-    # decode: no-drop capacity (a dropped decode token would corrupt the
-    # stream); train/prefill: the configured capacity factor
-    cf = -1.0 if mode == "decode" else cfg.capacity_factor
-    y, aux = moe_mlp(h, p["router"], p["we_gate"], p["we_up"], p["we_down"],
-                     topk=cfg.moe_topk, capacity_factor=cf,
-                     group_size=cfg.moe_group_size,
-                     dispatch=cfg.moe_dispatch, shard=shard)
-    return shard(y, ("batch", "seq", "embed")), aux
+    rule = dict(rule=cfg.moe_router, bias=p.get("router_bias"),
+                scale=cfg.moe_routed_scale)
+    rows = None
+    if mode == "train":
+        y, aux = moe_mlp(h, p["router"], p["we_gate"], p["we_up"],
+                         p["we_down"], topk=cfg.moe_topk,
+                         capacity_factor=cfg.capacity_factor,
+                         group_size=cfg.moe_group_size,
+                         dispatch=cfg.moe_dispatch, shard=shard, **rule)
+    else:
+        B, S, D = h.shape
+        y, rows = moe_dropless(h.reshape(B * S, D), p["router"],
+                               p["we_gate"], p["we_up"], p["we_down"],
+                               topk=cfg.moe_topk,
+                               first=cfg.held[0], **rule)
+        y, aux = y.reshape(B, S, D), jnp.float32(0.0)
+    if cfg.shared_ff:
+        y = y + layers.swiglu(h, p["ws_gate"], p["ws_up"], p["ws_down"])
+    return shard(y, ("batch", "seq", "embed")), aux, rows
 
 
 def apply_block(cfg: ArchConfig, kind: str, p: dict, x: jax.Array, *,
                 positions, mode: str, cache, pos, shard: ShardPolicy,
                 mrope_pos=None, cross_src=None):
     """One decoder block (mixer + FFN [+ cross-attn]). Returns
-    (x, new_cache, aux_loss)."""
-    aux = jnp.float32(0.0)
+    (x, new_cache, aux_loss, rows of each held expert or None)."""
+    aux, rows = jnp.float32(0.0), None
     new_cache: dict = {}
     if kind in ("mlstm", "slstm"):
         mixer = mlstm_mixer if kind == "mlstm" else slstm_mixer
@@ -158,10 +213,15 @@ def apply_block(cfg: ArchConfig, kind: str, p: dict, x: jax.Array, *,
         x = x + shard(y, ("batch", "seq", "embed"))
         if st is not None:
             new_cache.update(st)
-        return x, new_cache, aux
+        return x, new_cache, aux, rows
 
     mixer_kind, ffn_kind = kind.split("+")
-    if mixer_kind == "attn":
+    if mixer_kind == "attn" and cfg.kv_lora_rank:
+        y, kvc = _mla_attention(cfg, p, x, positions, mode, cache, pos,
+                                shard)
+        x = x + y
+        new_cache.update(kvc)
+    elif mixer_kind == "attn":
         y, kvc = _attention(cfg, p, x, positions, mode, cache, pos, shard,
                             mrope_pos=mrope_pos)
         x = x + y
@@ -183,41 +243,38 @@ def apply_block(cfg: ArchConfig, kind: str, p: dict, x: jax.Array, *,
         new_cache.update(xc)
 
     if ffn_kind == "moe":
-        y, aux = _moe(cfg, p, x, shard, mode)
+        y, aux, rows = _moe(cfg, p, x, shard, mode)
         x = x + y
     elif cfg.d_ff or cfg.dense_ff:
         x = x + _mlp(cfg, p, x, shard)
-    return x, new_cache, aux
+    return x, new_cache, aux, rows
 
 
 # ------------------------------------------------------------- the stack -
-def _block_key(bi: int, kind: str) -> str:
-    return f"b{bi}_{kind.replace('+', '_')}"
-
-
-def decoder_stack(cfg: ArchConfig, params: dict, x: jax.Array, *,
-                  positions, mode: str, caches, pos, shard: ShardPolicy,
-                  mrope_pos=None, cross_src=None):
-    """Scan the super-block pattern over x. caches: dict block_key →
-    pytree stacked on the super-block axis (or None)."""
-    pattern = block_pattern(cfg)
-    n_super = cfg.n_layers // len(pattern)
+def _scan_group(cfg: ArchConfig, pattern: list, lead: bool, stacked: dict,
+                caches, x, *, positions, mode: str, pos, shard: ShardPolicy,
+                mrope_pos=None, cross_src=None):
+    """Scan one group of layers, ``pattern`` repeated along the leading
+    axis of ``stacked``. Returns (x, new caches, aux loss, rows)."""
+    n_super = jax.tree.leaves(stacked)[0].shape[0]
     want_cache = mode in ("prefill", "decode")
 
     def super_block(x, block_params, block_caches):
-        new_caches = {}
+        new_caches, rows = {}, {}
         aux_sum = jnp.float32(0.0)
         for bi, kind in enumerate(pattern):
-            key = _block_key(bi, kind)
-            x, nc, aux = apply_block(
+            key = block_key(bi, kind, lead)
+            x, nc, aux, r = apply_block(
                 cfg, kind, block_params[key], x,
                 positions=positions, mode=mode,
                 cache=block_caches.get(key) if block_caches else None,
                 pos=pos, shard=shard, mrope_pos=mrope_pos,
                 cross_src=cross_src)
             new_caches[key] = nc
+            if r is not None:
+                rows[key] = r
             aux_sum = aux_sum + aux
-        return x, new_caches, aux_sum
+        return x, new_caches, aux_sum, rows
 
     if cfg.remat and mode == "train":
         super_block = jax.checkpoint(super_block)
@@ -225,26 +282,51 @@ def decoder_stack(cfg: ArchConfig, params: dict, x: jax.Array, *,
     def scan_body(carry, xs):
         x, aux_acc = carry
         block_params, block_caches = xs
-        x, new_caches, aux = super_block(x, block_params, block_caches)
-        return (x, aux_acc + aux), new_caches
+        x, new_caches, aux, rows = super_block(x, block_params, block_caches)
+        return (x, aux_acc + aux), (new_caches, rows)
 
-    stacked = params["blocks"]
     caches_xs = caches if caches is not None else {k: {} for k in stacked}
     if cfg.scan_layers and n_super > 1:
-        (x, aux), new_caches = jax.lax.scan(
+        (x, aux), (new_caches, rows) = jax.lax.scan(
             scan_body, (x, jnp.float32(0.0)), (stacked, caches_xs))
     else:
         # unrolled (n_super == 1 or scan disabled)
         aux = jnp.float32(0.0)
-        new_list = []
+        outs = []
         for i in range(n_super):
             sl = jax.tree.map(lambda a: a[i], stacked)
             cl = jax.tree.map(lambda a: a[i], caches_xs) if caches else None
-            (x, aux), nc = scan_body((x, aux), (sl, cl))
-            new_list.append(nc)
-        new_caches = jax.tree.map(lambda *zs: jnp.stack(zs), *new_list) \
-            if want_cache else None
-    return x, (new_caches if want_cache else None), aux
+            (x, aux), out = scan_body((x, aux), (sl, cl))
+            outs.append(out)
+        new_caches, rows = jax.tree.map(lambda *zs: jnp.stack(zs), *outs)
+    return x, (new_caches if want_cache else None), aux, rows
+
+
+def decoder_stack(cfg: ArchConfig, params: dict, x: jax.Array, *,
+                  positions, mode: str, caches, pos, shard: ShardPolicy,
+                  mrope_pos=None, cross_src=None):
+    """The leading dense layers, then the super-block pattern, over x.
+    caches: dict block_key → pytree stacked on its group's layer axis
+    (or None). Returns (x, new caches, aux loss, rows), where rows maps
+    each MoE block key to its (layers, held experts) int32 row counts
+    (serving modes only)."""
+    groups = [(block_pattern(cfg), False, params["blocks"])]
+    if cfg.n_dense_layers:
+        groups.insert(0, ([LEAD], True, params["lead_blocks"]))
+    new_caches, rows, aux = {}, {}, jnp.float32(0.0)
+    for pattern, lead, stacked in groups:
+        group_caches = None if caches is None else \
+            {k: caches[k] for k in stacked}
+        x, nc, a, r = _scan_group(
+            cfg, pattern, lead, stacked, group_caches, x,
+            positions=positions, mode=mode, pos=pos, shard=shard,
+            mrope_pos=mrope_pos, cross_src=cross_src)
+        if nc is not None:
+            new_caches.update(nc)
+        rows.update(r)
+        aux = aux + a
+    return x, (new_caches if mode in ("prefill", "decode") else None), \
+        aux, rows
 
 
 # ------------------------------------------------------------ full model -
@@ -280,11 +362,26 @@ def forward(cfg: ArchConfig, params: dict, batch: dict, *,
     """Full forward. Returns (logits, new_caches, aux)."""
     x, positions = embed_inputs(cfg, params, batch, shard)
     mrope_pos = batch.get("mrope_positions")
-    x, new_caches, aux = decoder_stack(
+    x, new_caches, aux, _ = decoder_stack(
         cfg, params, x, positions=positions, mode=mode, caches=caches,
         pos=pos, shard=shard, mrope_pos=mrope_pos, cross_src=cross_src)
     logits = lm_head(cfg, params, x, shard)
     return logits, new_caches, aux
+
+
+def forward_last(cfg: ArchConfig, params: dict, batch: dict,
+                 shard: ShardPolicy = NO_SHARD):
+    """A prefill that keeps only what a miss needs: the final norm and
+    head at the last position, (B, vocab) logits, with no caches. Also
+    returns each MoE layer's rows per held expert, (MoE layers, held)
+    int32, or None for a model without experts."""
+    x, positions = embed_inputs(cfg, params, batch, shard)
+    x, _, _, rows = decoder_stack(
+        cfg, params, x, positions=positions, mode="prefill", caches=None,
+        pos=0, shard=shard)
+    logits = lm_head(cfg, params, x[:, -1:], shard)[:, 0]
+    rows = jnp.concatenate(list(rows.values())) if rows else None
+    return logits, rows
 
 
 # ---------------------------------------------------------------- caches -
@@ -293,13 +390,16 @@ def init_cache(cfg: ArchConfig, batch_size: int, max_len: int,
     """Statically-shaped serving cache for decode."""
     dt = jnp.dtype(dtype or cfg.compute_dtype)
     pattern = block_pattern(cfg)
-    n_super = cfg.n_layers // len(pattern)
+    n_super = cfg.pattern_layers // len(pattern)
     kh, dh = cfg.n_kv_heads, cfg.head_dim
     di, n, cw = cfg.d_inner, cfg.ssm_state, cfg.ssm_conv
     nh = cfg.n_heads
     caches = {}
-    for bi, kind in enumerate(pattern):
-        key = _block_key(bi, kind)
+    groups = [(bi, kind, False, n_super) for bi, kind in enumerate(pattern)]
+    if cfg.n_dense_layers:
+        groups.insert(0, (0, LEAD, True, cfg.n_dense_layers))
+    for bi, kind, lead, n_super in groups:
+        key = block_key(bi, kind, lead)
         if kind == "mlstm":
             dhe = cfg.ssm_expand * cfg.d_model // nh
             caches[key] = {
@@ -314,7 +414,12 @@ def init_cache(cfg: ArchConfig, batch_size: int, max_len: int,
             continue
         mixer, _ = kind.split("+")
         c = {}
-        if mixer == "attn":
+        if mixer == "attn" and cfg.kv_lora_rank:
+            c["ckv"] = jnp.zeros((n_super, batch_size, max_len,
+                                  cfg.kv_lora_rank), dt)
+            c["kpe"] = jnp.zeros((n_super, batch_size, max_len,
+                                  cfg.qk_rope_dim), dt)
+        elif mixer == "attn":
             if cfg.kv_cache_dtype == "int8":
                 c["k"] = jnp.zeros((n_super, batch_size, max_len, kh, dh),
                                    jnp.int8)

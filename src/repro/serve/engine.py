@@ -182,18 +182,17 @@ def prefill_pieces(n: int, seq: int, lo: int = 8) -> list[int]:
     return [1 << i for i in range(r.bit_length() - 1, -1, -1) if r >> i & 1]
 
 
-def _first_piece(logits, rows: int):
-    """A (rows, vocab) array: ``logits``' last position, then zeros."""
-    last = logits[:, -1, :]
+def _first_piece(last, rows: int):
+    """A (rows, vocab) array: a piece's last-position logits, then
+    zeros."""
     return jnp.zeros((rows, last.shape[1]), last.dtype).at[
         :last.shape[0]].set(last)
 
 
-def _next_piece(out, logits, at):
-    """``out`` with rows [at, at + len(logits)) set to ``logits``' last
-    position."""
-    return jax.lax.dynamic_update_slice_in_dim(out, logits[:, -1, :], at,
-                                               axis=0)
+def _next_piece(out, last, at):
+    """``out`` with rows [at, at + len(last)) set to a piece's
+    last-position logits."""
+    return jax.lax.dynamic_update_slice_in_dim(out, last, at, axis=0)
 
 
 def _pad_rows(x, m: int):
@@ -377,7 +376,13 @@ class SimCacheEngine:
         self.stats = ServeStats()
         self.duel: DuelPlane | None = None                # online §5 plane
         self.placement_events = 0                         # duel churn count
-        self._prefill = jax.jit(model_api.make_prefill(cfg))
+        # the miss prefill, ``jit_prefill``: last-position logits and the
+        # MoE layers' rows per held expert (None without experts)
+        self._prefill = jax.jit(model_api.make_prefill_last(cfg))
+        # the last prefill's per-piece row counts: kept here, not returned,
+        # because ``serve`` calls the public ``prefill`` (which callers may
+        # wrap to record its logits) and that keeps its (bucket, vocab) return
+        self._expert_rows: list = []
         # (output bucket, prompt length) → the programs that lay the
         # pieces of a split prefill into the bucket's logits
         self._assembly: dict[tuple[int, int], tuple] = {}
@@ -441,45 +446,60 @@ class SimCacheEngine:
         bucket shape it also compiles, the first time, the programs that
         lay pieces into that bucket, so a split batch of host (NumPy)
         tokens compiles nothing; device tokens add an eager slice per
-        piece shape."""
+        piece shape. The program applies the final norm and head at the
+        last position only; a MoE model's per-piece row counts of its
+        held experts stay on the device for ``serve`` to fetch."""
         with tracecount.span("engine.prefill"):
             b, seq = tokens.shape
             lo = self.ecfg.min_bucket
             pieces = prefill_pieces(b, seq, lo) if self.ecfg.bucket else [b]
             if len(pieces) == 1 or sum(pieces) != b:
-                logits, _ = self._prefill(self.params, {"tokens": tokens})
+                last, rows = self._prefill(self.params, {"tokens": tokens})
+                self._expert_rows = [rows]
                 if self.ecfg.bucket and b == bucket_size(b, lo):
-                    self._assemble(b, logits)
-                return logits[:, -1, :]
-            out, at = None, 0
+                    self._assemble(b, seq, last)
+                return last
+            out, at, self._expert_rows = None, 0, []
             for p in pieces:
-                logits, _ = self._prefill(self.params,
-                                          {"tokens": tokens[at:at + p]})
-                first, rest = self._assemble(bucket_size(b, lo), logits)
-                out = first(logits) if out is None \
-                    else rest[p](out, logits, np.int32(at))
+                last, rows = self._prefill(self.params,
+                                           {"tokens": tokens[at:at + p]})
+                self._expert_rows.append(rows)
+                first, rest = self._assemble(bucket_size(b, lo), seq, last)
+                out = first(last) if out is None \
+                    else rest[p](out, last, np.int32(at))
                 at += p
             return out
 
-    def _assemble(self, b: int, logits: jax.Array) -> tuple:
-        """(first, {rows: next}) for output bucket ``b`` at the prompt
-        length of ``logits`` (a prefill's (rows, S, vocab) output),
+    def _count_expert_rows(self, rows: list, tokens: int) -> None:
+        """Counters of the MoE layers of one served prefill: the
+        token-expert assignments the device routed (``tokens`` × top-k ×
+        MoE layers), the rows the held experts computed, and per piece
+        and layer the busiest held expert's rows, summed. ``rows``: one
+        (MoE layers, held) array per piece."""
+        tracecount.add("moe.assignments",
+                       tokens * self.cfg.moe_topk * rows[0].shape[0])
+        tracecount.add("moe.rows_held", int(sum(r.sum() for r in rows)))
+        tracecount.add("moe.rows_busiest",
+                       int(sum(r.max(axis=1).sum() for r in rows)))
+
+    def _assemble(self, b: int, seq: int, last: jax.Array) -> tuple:
+        """(first, {rows: next}) for output bucket ``b`` at prompt length
+        ``seq``, given a prefill's (rows, vocab) last-position logits,
         compiled on first use: ``first`` lays the first piece, b/2 rows,
         into a new (b, vocab) array; ``next[p]`` lays a p-row piece at a
         traced offset, in place, for each p from the piece floor to b/4.
         Nothing where no plan splits a batch of bucket ``b``."""
-        seq, vocab = logits.shape[1:]
+        vocab = last.shape[1]
         key = (b, seq)
         if key not in self._assembly:
             f = piece_floor(seq, self.ecfg.min_bucket)
             first, rest = None, {}
             if b // 2 >= f:
                 def piece(p):
-                    return jax.ShapeDtypeStruct((p, seq, vocab),
-                                                logits.dtype)
+                    return jax.ShapeDtypeStruct((p, vocab), last.dtype)
                 first = jax.jit(_first_piece, static_argnums=1).lower(
                     piece(b // 2), b).compile()
-                out = jax.ShapeDtypeStruct((b, vocab), logits.dtype)
+                out = jax.ShapeDtypeStruct((b, vocab), last.dtype)
                 at = jax.ShapeDtypeStruct((), jnp.int32)
                 rest = {p: jax.jit(_next_piece, donate_argnums=0).lower(
                             out, piece(p), at).compile()
@@ -920,9 +940,14 @@ class SimCacheEngine:
             tracecount.add("prefill.pieces", len(pieces))
             logits = self.prefill(sel)
             with tracecount.span("serve.fetch_prefill"):
-                resp = np.asarray(jnp.argmax(logits, axis=-1))
+                resp, rows = jax.device_get(
+                    (jnp.argmax(logits, axis=-1), self._expert_rows))
             tracecount.add("serve.copies")
             tracecount.add("serve.copy_bytes", resp.nbytes)
+            if rows[0] is not None:
+                tracecount.add("serve.copy_bytes",
+                               sum(r.nbytes for r in rows))
+                self._count_expert_rows(rows, sel.shape[0] * sel.shape[1])
             self.stats.model_calls += 1
             if self.routing is None and self.simcache is None:
                 # cold engine without a strategy plane: repository cost

@@ -25,12 +25,14 @@ PUBLISHED_SIZES = {           # ±5% unless noted
     "xlstm-350m": 0.35e9,     # ±40%: block internals are ours (DESIGN.md)
     "whisper-small": 0.244e9,  # ±20%: conv frontend stubbed
     "qwen2-vl-7b": 7.6e9,     # backbone only (vision tower stubbed)
+    "moonlight-16b-a3b": 16e9,
 }
 
 ACTIVE_SIZES = {
     "jamba-1.5-large-398b": 94e9,
     "granite-moe-3b-a800m": 0.8e9,
     "dbrx-132b": 36e9,
+    "moonlight-16b-a3b": 3e9,
 }
 
 

@@ -13,6 +13,10 @@ chip of a ``v5e:2x2`` topology at the size the serving path runs it:
 * flash attention at granite-3-2b's head widths;
 * the granite-3-2b prefill at its published widths with bf16
   parameters, which must fit the chip's 16 GB;
+* the Moonlight-16B-A3B miss prefill (``jit_prefill``: latent
+  attention, the held experts' grouped matmuls, the head at the last
+  position) at its published widths with 8 of 64 experts held, at the
+  row counts its 1,024-token pieces run;
 * the programs that lay a split miss prefill's pieces into its
   bucket's logits, the later pieces in place.
 
@@ -127,18 +131,44 @@ def test_granite_prefill_fits_one_chip_with_bf16_params(one_chip):
     assert used < V5E_HBM_BYTES, used
 
 
+@pytest.mark.parametrize("rows", [8, 16, 32])
+def test_moonlight_prefill_fits_one_chip(one_chip, rows):
+    """The serving path's miss prefill of Moonlight-16B-A3B at its
+    published widths, 8 of its 64 routed experts held, bf16, at each row
+    count of its 1,024-token pieces: arguments (6.7 GB of weights) plus
+    temporaries stay under the chip's HBM, the held experts run as
+    grouped-matmul kernels, and the program keeps the name the
+    benchmark reads."""
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"),
+                              param_dtype="bfloat16", experts_held=(0, 8))
+    params = jax.eval_shape(lambda: model_api.init_params(cfg, 0))
+    params = jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), params)
+    tokens = _spec(one_chip, (rows, 1024), jnp.int32)
+    compiled = jax.jit(model_api.make_prefill_last(cfg)).lower(
+        params, {"tokens": tokens}).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < V5E_HBM_BYTES, used
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_prefill")
+    assert "ragged-dot" in text
+    assert compiled.out_info[0].shape == (rows, cfg.vocab)
+
+
 @pytest.mark.parametrize("rows,piece", [(64, 32), (64, 8), (1024, 16)])
 def test_prefill_pieces_assemble_in_place(one_chip, rows, piece):
     """The programs that lay a split prefill's pieces into its bucket's
-    logits, at granite-3-2b's vocabulary in bf16: the first piece into a
-    new (rows, vocab) array, a later one at a traced offset into the
-    donated array, which the output then aliases."""
+    logits, at granite-3-2b's vocabulary in bf16: the first piece's
+    last-position logits into a new (rows, vocab) array, a later one at
+    a traced offset into the donated array, which the output then
+    aliases."""
     from repro.serve.engine import _first_piece, _next_piece
-    vocab, seq = 49155, 128 if rows == 64 else 16
-    logits = _spec(one_chip, (piece, seq, vocab), jnp.bfloat16)
+    vocab = 49155
+    logits = _spec(one_chip, (piece, vocab), jnp.bfloat16)
     out = _spec(one_chip, (rows, vocab), jnp.bfloat16)
     first = jax.jit(_first_piece, static_argnums=1).lower(
-        _spec(one_chip, (rows // 2, seq, vocab), jnp.bfloat16),
+        _spec(one_chip, (rows // 2, vocab), jnp.bfloat16),
         rows).compile()
     assert first.out_info.shape == (rows, vocab)
     nxt = jax.jit(_next_piece, donate_argnums=0).lower(

@@ -229,3 +229,46 @@ def test_phase_table_prints_every_span_and_counter(warm_engine, capsys):
                  "lookup.rows_valid", "prefill.rows", "prefill.rows_valid",
                  "serve.copies", "serve.copy_bytes"):
         assert int(rows[name][0]) >= summary["counts"][name] > 0
+
+
+def test_moe_counters_count_the_routed_and_held_rows(warm_engine):
+    """A MoE model's served prefill counts the router's assignments on
+    the device, the rows its held experts computed and the busiest held
+    expert's rows per layer; the prefill counters keep their meaning. A
+    dense model's serve counts no MoE rows."""
+    import dataclasses
+    from repro.configs.registry import get_smoke_config
+    from repro.models import model as model_api
+    from repro.serve import EngineConfig, SimCacheEngine
+    dense, dense_cfg, cat = warm_engine
+    cfg = dataclasses.replace(get_smoke_config("moonlight-16b-a3b"),
+                              experts_held=(2, 4))
+    eng = SimCacheEngine(cfg, model_api.init_params(cfg, 0),
+                         EngineConfig(h_model=100.0), cat.coords)
+    seq = 64                  # 24 cold misses run as pieces of 16 and 8
+    prompts = jnp.asarray(np.random.default_rng(11).integers(
+        0, cfg.vocab, (24, seq)).astype(np.int32))
+    with tracecount.snapshot() as s:
+        eng.serve(np.arange(24), prompts)
+    pieces = prefill_pieces(24, seq, eng.ecfg.min_bucket)
+    assert pieces == [16, 8]
+    rows = [np.asarray(r) for r in eng._expert_rows]
+    assert [r.shape for r in rows] == [(2, 4), (2, 4)]
+    assert s.delta("moe.assignments") == sum(pieces) * seq * 3 * 2
+    assert s.delta("moe.rows_held") == sum(int(r.sum()) for r in rows)
+    assert 0 < s.delta("moe.rows_held") < s.delta("moe.assignments")
+    assert s.delta("moe.rows_busiest") == sum(
+        int(r.max(axis=1).sum()) for r in rows)
+    assert s.delta("prefill.rows") == sum(pieces)
+    assert s.delta("prefill.rows_valid") == 24
+    assert s.delta("prefill.batches") == 1
+    assert s.delta("prefill.pieces") == len(pieces)
+    # the argmax of the 24 rows' bucket and the two pieces' row counts
+    assert s.delta("serve.copy_bytes") == 4 * 32 + 2 * 2 * 4 * 4
+    (ids, prompts), = _uniform_batches(cat, dense_cfg, [24], seed=12)
+    with tracecount.snapshot() as s:
+        dense.serve(ids, prompts)
+    assert s.delta("prefill.rows") > 0
+    assert all(s.delta(k) == 0 for k in ("moe.assignments",
+                                         "moe.rows_held",
+                                         "moe.rows_busiest"))
