@@ -26,8 +26,11 @@ touching a jitted entry point — the fused lookup, the duel scan
 (``DuelPlane.observe(n_valid=…)``), and the miss-prefill each compile
 once per *bucket*, not once per distinct batch size. The miss prefill
 pads less: its sub-batch runs as power-of-two pieces no smaller than
-``PIECE_TOKENS`` tokens (``prefill_pieces``), each a bucket shape, so
-~44 misses run as 32 + 16 rows rather than as 64. Padding rows are
+``PIECE_TOKENS`` tokens (``prefill_pieces``; a piece below the smallest
+bucket holds ``SMALL_PIECE_TOKENS``), so ~44 misses of 128
+tokens run as 32 + 16 rows rather than as 64, and 5 of 1,024 tokens as
+4 + 1 rather than as the smallest bucket's 8. A bucket's first prefill
+compiles every piece shape below it. Padding rows are
 masked everywhere: they never enter ``counts``, ``ServeStats``, the
 duel trajectory (bit-identical to the unpadded one — the masked-scan
 contract of core/placement/netduel.py), or the responses returned.
@@ -158,22 +161,30 @@ def bucket_size(n: int, lo: int = 8) -> int:
 # stops being bound by HBM on a TPU v5e: a token costs 2 FLOP per 2-byte
 # weight, and the chip's ridge is 197e12 / 819e9 ≈ 240 FLOP per byte.
 PIECE_TOKENS = 256
+# Tokens a piece of fewer rows than the smallest bucket must hold. On a
+# TPU v5e, jit_prefill of granite-3-2b's 128-token rows ran 27 % (2 rows)
+# and 7.5 % (4 rows) slower per token than at 8 rows, while 1,024-token
+# rows of Moonlight-16B-A3B ran within 6 % of their 8-row rate from 1 row
+# up: pieces below the bucket pay off for long prompts only.
+SMALL_PIECE_TOKENS = 1024
 
 
 def piece_floor(seq: int, lo: int = 8) -> int:
     """Fewest rows of a prefill piece of ``seq``-token prompts: the
-    smallest bucket (≥ ``lo``) that holds ``PIECE_TOKENS`` tokens."""
-    return bucket_size(-(-PIECE_TOKENS // seq), lo)
+    smallest power of two whose rows hold ``PIECE_TOKENS`` tokens, and
+    whose rows, where fewer than ``lo``, hold ``SMALL_PIECE_TOKENS``."""
+    f = bucket_size(-(-PIECE_TOKENS // seq), 1)
+    return max(f, min(lo, bucket_size(-(-SMALL_PIECE_TOKENS // seq), 1)))
 
 
 def prefill_pieces(n: int, seq: int, lo: int = 8) -> list[int]:
     """Row counts, largest first, of the prefills that run a miss batch
     of ``n`` prompts of ``seq`` tokens. The rows are rounded up to a
     multiple of the piece floor and split into distinct powers of two;
-    where that is not fewer rows than the batch's bucket, the bucket runs
-    as one piece. So a plan never runs more rows than
-    ``bucket_size(n, lo)``, and every piece is a bucket shape the prefill
-    already compiles. Of several pieces the first is half the bucket."""
+    where that is not fewer rows than the batch's bucket,
+    ``bucket_size(n, lo)``, the bucket runs as one piece. So a plan never
+    runs more rows than that bucket; with a floor of one row it is the
+    binary digits of ``n`` (7 → [4, 2, 1])."""
     b = bucket_size(n, lo)
     f = piece_floor(seq, lo)
     r = -(-n // f) * f
@@ -437,26 +448,28 @@ class SimCacheEngine:
         """Run the repository model on a (B, S) token batch; returns the
         logits of the last position.
 
-        With ``EngineConfig.bucket``, B rows that are exactly a plan of
-        several pieces (``prefill_pieces(B, S, min_bucket)``, as ``serve``
-        pads a miss batch) run as those power-of-two prefills, back to
-        back with no host sync, and come back as one (bucket_size(B),
-        vocab) array whose first B rows are the pieces' rows in order.
-        Any other batch is one prefill with (B, vocab) logits; at a
-        bucket shape it also compiles, the first time, the programs that
-        lay pieces into that bucket, so a split batch of host (NumPy)
-        tokens compiles nothing; device tokens add an eager slice per
-        piece shape. The program applies the final norm and head at the
-        last position only; a MoE model's per-piece row counts of its
-        held experts stay on the device for ``serve`` to fetch."""
+        With ``EngineConfig.bucket``, B rows that are exactly a plan
+        (``prefill_pieces(B, S, min_bucket)``, as ``serve`` pads a miss
+        batch) of fewer rows than their bucket run as that plan's
+        power-of-two prefills, back to back with no host sync, and come
+        back as one (bucket_size(B), vocab) array whose first B rows are
+        the pieces' rows in order. Any other batch is one prefill with
+        (B, vocab) logits; at a bucket shape it also compiles, the first
+        time, every program a plan below that bucket runs (the prefill
+        at each piece size, and the programs that lay pieces into the
+        bucket), so a split batch of host (NumPy) tokens compiles
+        nothing; device tokens add an eager slice per piece shape. The
+        program applies the final norm and head at the last position
+        only; a MoE model's per-piece row counts of its held experts
+        stay on the device for ``serve`` to fetch."""
         with tracecount.span("engine.prefill"):
             b, seq = tokens.shape
             lo = self.ecfg.min_bucket
-            pieces = prefill_pieces(b, seq, lo) if self.ecfg.bucket else [b]
-            if len(pieces) == 1 or sum(pieces) != b:
+            pieces, out_b = prefill_pieces(b, seq, lo), bucket_size(b, lo)
+            if not self.ecfg.bucket or b == out_b or sum(pieces) != b:
                 last, rows = self._prefill(self.params, {"tokens": tokens})
                 self._expert_rows = [rows]
-                if self.ecfg.bucket and b == bucket_size(b, lo):
+                if self.ecfg.bucket and b == out_b:
                     self._assemble(b, seq, last)
                 return last
             out, at, self._expert_rows = None, 0, []
@@ -464,8 +477,8 @@ class SimCacheEngine:
                 last, rows = self._prefill(self.params,
                                            {"tokens": tokens[at:at + p]})
                 self._expert_rows.append(rows)
-                first, rest = self._assemble(bucket_size(b, lo), seq, last)
-                out = first(last) if out is None \
+                first, rest = self._assemble(out_b, seq, last)
+                out = first[p](last) if out is None \
                     else rest[p](out, last, np.int32(at))
                 at += p
             return out
@@ -483,29 +496,38 @@ class SimCacheEngine:
                        int(sum(r.max(axis=1).sum() for r in rows)))
 
     def _assemble(self, b: int, seq: int, last: jax.Array) -> tuple:
-        """(first, {rows: next}) for output bucket ``b`` at prompt length
-        ``seq``, given a prefill's (rows, vocab) last-position logits,
-        compiled on first use: ``first`` lays the first piece, b/2 rows,
-        into a new (b, vocab) array; ``next[p]`` lays a p-row piece at a
-        traced offset, in place, for each p from the piece floor to b/4.
-        Nothing where no plan splits a batch of bucket ``b``."""
-        vocab = last.shape[1]
+        """({rows: first}, {rows: next}) for output bucket ``b`` at prompt
+        length ``seq``, given a prefill's (rows, vocab) last-position
+        logits, compiled on first use for every plan of fewer rows than
+        ``b`` that a batch of bucket ``b`` can run: ``first[p]`` lays a
+        first piece of p rows into a new (b, vocab) array, ``next[p]`` a
+        later one at a traced offset, in place. The first use also runs
+        the prefill once at each of those piece sizes below
+        ``min_bucket``, a shape no bucket compiles. Nothing where no plan
+        splits a batch of bucket ``b``."""
         key = (b, seq)
         if key not in self._assembly:
-            f = piece_floor(seq, self.ecfg.min_bucket)
-            first, rest = None, {}
-            if b // 2 >= f:
-                def piece(p):
-                    return jax.ShapeDtypeStruct((p, vocab), last.dtype)
-                first = jax.jit(_first_piece, static_argnums=1).lower(
-                    piece(b // 2), b).compile()
-                out = jax.ShapeDtypeStruct((b, vocab), last.dtype)
-                at = jax.ShapeDtypeStruct((), jnp.int32)
-                rest = {p: jax.jit(_next_piece, donate_argnums=0).lower(
-                            out, piece(p), at).compile()
-                        for p in (f << k for k in range(
-                            (b // 4 // f).bit_length()))}
-            self._assembly[key] = first, rest
+            lo = self.ecfg.min_bucket
+            plans = [p for p in (prefill_pieces(n, seq, lo)
+                                 for n in range(1, b + 1)
+                                 if bucket_size(n, lo) == b)
+                     if sum(p) < b]
+            firsts = {p[0] for p in plans}
+            nexts = {q for p in plans for q in p[1:]}
+            for p in sorted(r for r in firsts | nexts if r < lo):
+                self._prefill(self.params,
+                              {"tokens": np.zeros((p, seq), np.int32)})
+            vocab, dtype = last.shape[1], last.dtype
+
+            def piece(p):
+                return jax.ShapeDtypeStruct((p, vocab), dtype)
+            out = jax.ShapeDtypeStruct((b, vocab), dtype)
+            at = jax.ShapeDtypeStruct((), jnp.int32)
+            self._assembly[key] = (
+                {p: jax.jit(_first_piece, static_argnums=1).lower(
+                    piece(p), b).compile() for p in sorted(firsts)},
+                {p: jax.jit(_next_piece, donate_argnums=0).lower(
+                    out, piece(p), at).compile() for p in sorted(nexts)})
         return self._assembly[key]
 
     # ------------------------------------------------------- calibration

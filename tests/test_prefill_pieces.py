@@ -1,7 +1,7 @@
 """The miss prefill's piece plan: a miss sub-batch runs as power-of-two
-prefills no smaller than the weight-streaming floor, never more rows
-than its single bucket, with the same tokens as that bucket and no
-compile once every bucket has run."""
+prefills no smaller than the weight-streaming floor, which may lie below
+the smallest bucket, never more rows than its single bucket, with the
+same tokens as that bucket and no compile once every bucket has run."""
 import dataclasses
 
 import jax
@@ -15,10 +15,11 @@ from repro.core import catalog as catalog_api
 from repro.models import model as model_api
 from repro.serve import (EngineConfig, SimCacheEngine, bucket_size,
                          prefill_pieces)
-from repro.serve.engine import PIECE_TOKENS, piece_floor
+from repro.serve.engine import PIECE_TOKENS, SMALL_PIECE_TOKENS, piece_floor
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 SEQ = 32            # 8-row piece floor: ~40 misses split as 32 + 8
+LONG = 1024         # 1-row piece floor: 7 misses split as 4 + 2 + 1
 
 
 def _tiny_engine(bucket=True):
@@ -35,9 +36,9 @@ def _tiny_engine(bucket=True):
     return eng, cfg, cat
 
 
-def _prompts(cfg, n, seed):
+def _prompts(cfg, n, seed, seq=SEQ):
     rng = np.random.default_rng(seed)
-    return rng.integers(0, cfg.vocab, (n, SEQ)).astype(np.int32)
+    return rng.integers(0, cfg.vocab, (n, seq)).astype(np.int32)
 
 
 def _is_pow2(x):
@@ -45,18 +46,23 @@ def _is_pow2(x):
 
 
 @pytest.mark.parametrize("lo", [8, 16])
-@pytest.mark.parametrize("seq", [16, 128])
+@pytest.mark.parametrize("seq", [16, 128, 1024])
 @pytest.mark.parametrize("n", [1, 8, 9, 37, 43, 44, 50, 57, 64, 700])
 def test_prefill_pieces_plan(n, seq, lo):
     pieces = prefill_pieces(n, seq, lo)
     b, f = bucket_size(n, lo), piece_floor(seq, lo)
-    assert _is_pow2(f) and f >= lo and f * seq >= PIECE_TOKENS
-    assert f == lo or (f // 2) * seq < PIECE_TOKENS
+    # the floor: the smallest power of two holding PIECE_TOKENS tokens,
+    # and SMALL_PIECE_TOKENS where it has fewer rows than the bucket
+    def holds(rows):
+        return rows * seq >= (PIECE_TOKENS if rows >= lo
+                              else SMALL_PIECE_TOKENS)
+    assert _is_pow2(f) and holds(f)
+    assert f == 1 or not holds(f // 2)
     assert all(_is_pow2(p) for p in pieces)
     assert all(a > c for a, c in zip(pieces, pieces[1:]))   # descending
     assert n <= sum(pieces) <= b
     if pieces != [b]:
-        assert len(pieces) > 1 and pieces[0] == b // 2
+        assert pieces[0] <= b // 2       # the first piece is the largest
         assert all(p >= f for p in pieces)
         assert sum(pieces) < n + f
 
@@ -70,21 +76,27 @@ def test_prefill_pieces_plan(n, seq, lo):
     (11, 16, 8, [16]),
     (700, 16, 8, [512, 128, 64]),
     (3, 512, 1, [2, 1]),         # a floor of 1 row at 512 tokens
+    (1, 1024, 8, [1]),           # moonlight-resp.p1k: below the bucket
+    (5, 1024, 8, [4, 1]),
+    (7, 1024, 8, [4, 2, 1]),
+    (9, 1024, 8, [8, 1]),
+    (12, 1024, 8, [8, 4]),
 ])
 def test_prefill_pieces_known_plans(n, seq, lo, plan):
     assert prefill_pieces(n, seq, lo) == plan
 
 
-@pytest.mark.parametrize("n", [20, 37, 50])
-def test_split_batch_serves_the_bucket_tokens(n):
+@pytest.mark.parametrize("n,seq", [(20, SEQ), (37, SEQ), (50, SEQ),
+                                   (7, LONG)])
+def test_split_batch_serves_the_bucket_tokens(n, seq):
     """A cold batch of ``n`` misses runs as several pieces; its tokens are
     those of the same prompts prefilled at their one bucket, and its
     logits agree with the bucket's to bf16 precision."""
     eng, cfg, _ = _tiny_engine()
-    pieces = prefill_pieces(n, SEQ, eng.ecfg.min_bucket)
+    pieces = prefill_pieces(n, seq, eng.ecfg.min_bucket)
     b = bucket_size(n)
     assert len(pieces) > 1 and sum(pieces) < b
-    prompts = _prompts(cfg, n, seed=n)
+    prompts = _prompts(cfg, n, seed=n, seq=seq)
     ref, _ = eng._prefill(eng.params, {"tokens": np.concatenate(
         [prompts, np.repeat(prompts[:1], b - n, axis=0)])})
     ref = np.asarray(ref[:n], np.float32)
@@ -115,23 +127,25 @@ def test_unbucketed_prefill_runs_the_rows_as_given():
     assert s.delta("prefill.pieces") == 1
 
 
-def test_no_compile_after_bucket_warm_up():
+@pytest.mark.parametrize("seq,batch", [(SEQ, 64), (LONG, 32)])
+def test_no_compile_after_bucket_warm_up(seq, batch):
     """Warm up as the benchmark does (the prefill at every power-of-two
-    bucket, then one served batch), then serve batches of 64 requests
-    with 1 … 64 misses: the split prefills compile nothing."""
+    bucket, then one served batch), then serve batches of ``batch``
+    requests with 1 … ``batch`` misses: the split prefills compile
+    nothing, those with pieces below the smallest bucket included."""
     eng, cfg, cat = _tiny_engine()
-    warm = np.random.default_rng(0).integers(0, len(cat.coords), 64)
-    eng.serve(warm, _prompts(cfg, 64, seed=0))
+    warm = np.random.default_rng(0).integers(0, len(cat.coords), batch)
+    eng.serve(warm, _prompts(cfg, batch, seed=0, seq=seq))
     eng.refresh_placement()
     hit = np.asarray(eng.simcache.lookup(jnp.asarray(eng.coords)).hit)
     hits, misses = np.nonzero(hit)[0], np.nonzero(~hit)[0]
     assert len(hits) and len(misses)
     b = eng.ecfg.min_bucket
-    while b <= 64:
-        np.asarray(jnp.argmax(eng.prefill(np.zeros((b, SEQ), np.int32)),
+    while b <= batch:
+        np.asarray(jnp.argmax(eng.prefill(np.zeros((b, seq), np.int32)),
                               axis=-1))
         b *= 2
-    eng.serve(warm, _prompts(cfg, 64, seed=1))
+    eng.serve(warm, _prompts(cfg, batch, seed=1, seq=seq))
 
     rng = np.random.default_rng(2)
     compiles = []
@@ -143,18 +157,19 @@ def test_no_compile_after_bucket_warm_up():
     jax.monitoring.register_event_duration_secs_listener(on)
     try:
         with tracecount.snapshot() as s:
-            for m in range(1, 65):
+            for m in range(1, batch + 1):
                 ids = np.concatenate([rng.choice(misses, m),
-                                      rng.choice(hits, 64 - m)])
+                                      rng.choice(hits, batch - m)])
                 hits0 = eng.stats.n_hits
-                eng.serve(ids, _prompts(cfg, 64, seed=100 + m))
-                assert eng.stats.n_hits - hits0 == 64 - m
+                eng.serve(ids, _prompts(cfg, batch, seed=100 + m, seq=seq))
+                assert eng.stats.n_hits - hits0 == batch - m
     finally:
         jax.monitoring.unregister_event_duration_listener(on)
     assert compiles == []
-    plans = [prefill_pieces(m, SEQ) for m in range(1, 65)]
-    assert s.delta("prefill.batches") == 64
+    plans = [prefill_pieces(m, seq) for m in range(1, batch + 1)]
+    assert s.delta("prefill.batches") == batch
     assert s.delta("prefill.pieces") == sum(map(len, plans))
     assert s.delta("prefill.rows") == sum(map(sum, plans))
-    assert s.delta("prefill.rows_valid") == sum(range(1, 65))
+    assert s.delta("prefill.rows_valid") == sum(range(1, batch + 1))
     assert any(len(p) > 1 for p in plans)
+    assert (seq == LONG) == any(min(p) < eng.ecfg.min_bucket for p in plans)

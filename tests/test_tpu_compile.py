@@ -131,7 +131,7 @@ def test_granite_prefill_fits_one_chip_with_bf16_params(one_chip):
     assert used < V5E_HBM_BYTES, used
 
 
-@pytest.mark.parametrize("rows", [8, 16, 32])
+@pytest.mark.parametrize("rows", [1, 2, 4, 8, 16, 32])
 def test_moonlight_prefill_fits_one_chip(one_chip, rows):
     """The serving path's miss prefill of Moonlight-16B-A3B at its
     published widths, 8 of its 64 routed experts held, bf16, at each row
@@ -156,21 +156,23 @@ def test_moonlight_prefill_fits_one_chip(one_chip, rows):
     assert compiled.out_info[0].shape == (rows, cfg.vocab)
 
 
-@pytest.mark.parametrize("rows,piece", [(64, 32), (64, 8), (1024, 16)])
+@pytest.mark.parametrize("rows,piece", [(64, 32), (64, 8), (1024, 16),
+                                        (8, 2)])
 def test_prefill_pieces_assemble_in_place(one_chip, rows, piece):
     """The programs that lay a split prefill's pieces into its bucket's
-    logits, at granite-3-2b's vocabulary in bf16: the first piece's
-    last-position logits into a new (rows, vocab) array, a later one at
-    a traced offset into the donated array, which the output then
-    aliases."""
+    logits, at granite-3-2b's vocabulary in bf16: a first piece's
+    last-position logits (half the bucket, or fewer rows, as 3 misses
+    run as 2 + 1 in a bucket of 8) into a new (rows, vocab) array, a
+    later one at a traced offset into the donated array, which the
+    output then aliases."""
     from repro.serve.engine import _first_piece, _next_piece
     vocab = 49155
     logits = _spec(one_chip, (piece, vocab), jnp.bfloat16)
     out = _spec(one_chip, (rows, vocab), jnp.bfloat16)
-    first = jax.jit(_first_piece, static_argnums=1).lower(
-        _spec(one_chip, (rows // 2, vocab), jnp.bfloat16),
-        rows).compile()
-    assert first.out_info.shape == (rows, vocab)
+    for lead in sorted({rows // 2, piece}):
+        first = jax.jit(_first_piece, static_argnums=1).lower(
+            _spec(one_chip, (lead, vocab), jnp.bfloat16), rows).compile()
+        assert first.out_info.shape == (rows, vocab)
     nxt = jax.jit(_next_piece, donate_argnums=0).lower(
         out, logits, _spec(one_chip, (), jnp.int32)).compile()
     # the aliased buffer is the whole output, padded to the chip's tiles

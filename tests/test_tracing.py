@@ -231,7 +231,12 @@ def test_phase_table_prints_every_span_and_counter(warm_engine, capsys):
         assert int(rows[name][0]) >= summary["counts"][name] > 0
 
 
-def test_moe_counters_count_the_routed_and_held_rows(warm_engine):
+@pytest.mark.parametrize("misses,seq,plan", [
+    (24, 64, [16, 8]),        # pieces of whole buckets
+    (7, 1024, [4, 2, 1]),     # a 1-row floor: pieces below the bucket
+])
+def test_moe_counters_count_the_routed_and_held_rows(warm_engine, misses,
+                                                     seq, plan):
     """A MoE model's served prefill counts the router's assignments on
     the device, the rows its held experts computed and the busiest held
     expert's rows per layer; the prefill counters keep their meaning. A
@@ -245,26 +250,26 @@ def test_moe_counters_count_the_routed_and_held_rows(warm_engine):
                               experts_held=(2, 4))
     eng = SimCacheEngine(cfg, model_api.init_params(cfg, 0),
                          EngineConfig(h_model=100.0), cat.coords)
-    seq = 64                  # 24 cold misses run as pieces of 16 and 8
     prompts = jnp.asarray(np.random.default_rng(11).integers(
-        0, cfg.vocab, (24, seq)).astype(np.int32))
+        0, cfg.vocab, (misses, seq)).astype(np.int32))
     with tracecount.snapshot() as s:
-        eng.serve(np.arange(24), prompts)
-    pieces = prefill_pieces(24, seq, eng.ecfg.min_bucket)
-    assert pieces == [16, 8]
+        eng.serve(np.arange(misses), prompts)
+    pieces = prefill_pieces(misses, seq, eng.ecfg.min_bucket)
+    assert pieces == plan
     rows = [np.asarray(r) for r in eng._expert_rows]
-    assert [r.shape for r in rows] == [(2, 4), (2, 4)]
+    assert [r.shape for r in rows] == [(2, 4)] * len(plan)
     assert s.delta("moe.assignments") == sum(pieces) * seq * 3 * 2
     assert s.delta("moe.rows_held") == sum(int(r.sum()) for r in rows)
     assert 0 < s.delta("moe.rows_held") < s.delta("moe.assignments")
     assert s.delta("moe.rows_busiest") == sum(
         int(r.max(axis=1).sum()) for r in rows)
     assert s.delta("prefill.rows") == sum(pieces)
-    assert s.delta("prefill.rows_valid") == 24
+    assert s.delta("prefill.rows_valid") == misses
     assert s.delta("prefill.batches") == 1
     assert s.delta("prefill.pieces") == len(pieces)
-    # the argmax of the 24 rows' bucket and the two pieces' row counts
-    assert s.delta("serve.copy_bytes") == 4 * 32 + 2 * 2 * 4 * 4
+    # the argmax of the misses' bucket and each piece's row counts
+    assert s.delta("serve.copy_bytes") == (4 * bucket_size(misses)
+                                           + len(plan) * 2 * 4 * 4)
     (ids, prompts), = _uniform_batches(cat, dense_cfg, [24], seed=12)
     with tracecount.snapshot() as s:
         dense.serve(ids, prompts)
